@@ -1,21 +1,20 @@
 """Pipeline-stage throughput: the vectorized JAX group-by vs the Pig-style
 Python oracle, dictionary build, the LM batch pipeline feed rate, the
-full 3-stage log pipeline — single-host vs distributed on a host-local
-8-shard mesh (repartition -> dedup+sessionize -> ngram/funnel rollups) —
-and the streaming fast-data tier (micro-batch ticks through
+full 3-stage log pipeline — single-host vs distributed over a mesh of the
+devices present (repartition -> dedup+sessionize -> ngram/funnel rollups)
+— and the streaming fast-data tier (micro-batch ticks through
 repro.data.streampipe, checked bit-equal against the batch oracle)."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
+import jax
 import numpy as np
 
 from repro.core import EventDictionary, sessionize
 from repro.core.oracle import sessionize_oracle
 from repro.data import SessionBatchPipeline, PipelineConfig
+from repro.data.loggen import SIGNUP_FUNNEL
 from .common import corpus, timeit, row
 
 # Machine-readable payload for benchmarks/run.py --json (the CI gate parses
@@ -23,84 +22,58 @@ from .common import corpus, timeit, row
 LAST_JSON: dict | None = None
 JSON_PATH = "BENCH_pipeline.json"
 
-_FUNNEL = ("*:signup:landing:form:signup_button:click",
-           "*:signup:form:form:submit_button:submit",
-           "*:signup:follow_suggestions:list:user:follow",
-           "*:signup:complete:page::impression")
-
-# The host-local distributed run needs the device-count XLA flag set before
-# jax imports, so it lives in a subprocess. It times the SAME corpus and
-# funnel through both entry points and asserts the rollups agree before
-# reporting.
-_DIST_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time
-sys.path.insert(0, {src!r})
-import numpy as np, jax
-from repro.core import EventDictionary
-from repro.data import generate, LogGenConfig
-from repro.data.distpipe import (DistPipelineConfig,
-                                 make_distributed_pipeline,
-                                 single_host_pipeline)
-
-log = generate(LogGenConfig(n_users={n_users}, seed={seed}))
-b = log.batch
-d = EventDictionary.build(b.table, b.name_id)
-codes = np.asarray(d.encode_ids(b.name_id))
-stages = [d.codes_matching(p) for p in (
-    "*:signup:landing:form:signup_button:click",
-    "*:signup:form:form:submit_button:submit",
-    "*:signup:follow_suggestions:list:user:follow",
-    "*:signup:complete:page::impression")]
-n = len(b)
-ip = b.ip.astype(np.int64)
-cfg = DistPipelineConfig(alphabet_size=d.alphabet_size,
-                         max_sessions_per_shard=-(-n // 4), max_len=2048)
-
-def timed(fn, repeats=3):
-    out = fn()  # warmup (jit compile); result reused for the equivalence check
-    ts = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn()
-        ts.append((time.perf_counter() - t0) * 1e6)
-    return float(np.median(ts)), out
-
-us_single, ora = timed(lambda: single_host_pipeline(
-    b.user_id, b.session_id, b.timestamp, codes, ip, cfg=cfg, stages=stages))
-mesh = jax.make_mesh((8,), ("data",))
-pipe = make_distributed_pipeline(mesh, cfg, stages)
-us_dist, res = timed(
-    lambda: pipe(b.user_id, b.session_id, b.timestamp, codes, ip))
-
-assert res.dropped == 0
-assert res.num_sessions() == ora.num_sessions()
-assert np.array_equal(res.ngram_counts, ora.ngram_counts)
-assert res.funnel_reach == ora.funnel_reach
-print(f"DIST,{{n}},{{us_single:.1f}},{{us_dist:.1f}}")
-"""
-
 
 def _distpipe_rows(n_users: int = 2000, seed: int = 42) -> list[str]:
-    src = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
-    script = _DIST_SCRIPT.format(src=src, n_users=n_users, seed=seed)
-    out = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=1200)
-    if out.returncode != 0:
-        raise RuntimeError("distributed pipeline bench failed:\n"
-                           + out.stderr[-3000:])
-    line = next(l for l in out.stdout.splitlines() if l.startswith("DIST,"))
-    _, n, us_single, us_dist = line.split(",")
-    n, us_single, us_dist = int(n), float(us_single), float(us_dist)
+    """The same corpus and funnel through ``single_host_pipeline`` and the
+    distributed pipeline over every device of this process, in-process
+    (one process per chip); the rollups must agree before either row is
+    reported."""
+    from repro.data import generate, LogGenConfig
+    from repro.data.distpipe import (DistPipelineConfig,
+                                     make_distributed_pipeline,
+                                     single_host_pipeline)
+    from repro.dist import make_mesh
+
+    log = generate(LogGenConfig(n_users=n_users, seed=seed))
+    b = log.batch
+    d = EventDictionary.build(b.table, b.name_id)
+    codes = np.asarray(d.encode_ids(b.name_id))
+    stages = [d.codes_matching(p) for p in SIGNUP_FUNNEL]
+    n = len(b)
+    ip = b.ip.astype(np.int64)
+    n_dev = len(jax.devices())
+    cfg = DistPipelineConfig(alphabet_size=d.alphabet_size,
+                             max_sessions_per_shard=-(-n // n_dev),
+                             max_len=2048)
+
+    def timed(fn, repeats=3):
+        out = fn()  # warmup (jit compile); result reused for the check
+        ts = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            fn()
+            ts.append((time.perf_counter() - t0) * 1e6)
+        return float(np.median(ts)), out
+
+    us_single, ora = timed(lambda: single_host_pipeline(
+        b.user_id, b.session_id, b.timestamp, codes, ip, cfg=cfg,
+        stages=stages))
+    pipe = make_distributed_pipeline(make_mesh((n_dev,), ("data",)), cfg,
+                                      stages)
+    us_dist, res = timed(
+        lambda: pipe(b.user_id, b.session_id, b.timestamp, codes, ip))
+
+    assert res.dropped == 0
+    assert res.num_sessions() == ora.num_sessions()
+    assert np.array_equal(res.ngram_counts, ora.ngram_counts)
+    assert res.funnel_reach == ora.funnel_reach
     return [
         row("pipeline_single_host", us_single,
             f"{n / (us_single / 1e6) / 1e6:.2f}M events/s "
             "dedup+sessionize+ngram+funnel"),
-        row("pipeline_distributed_8shard", us_dist,
+        row(f"pipeline_distributed_{n_dev}shard", us_dist,
             f"{n / (us_dist / 1e6) / 1e6:.2f}M events/s "
-            "repartition+dedup+sessionize+rollups, 8 host shards"),
+            f"repartition+dedup+sessionize+rollups, {n_dev} shard(s)"),
     ]
 
 
@@ -119,7 +92,7 @@ def _stream_rows(n_users: int = 500, seed: int = 42,
     d = EventDictionary.build(b.table, b.name_id)
     codes = np.asarray(d.encode_ids(b.name_id), np.int32)
     ip = b.ip.astype(np.int64)
-    stages = [d.codes_matching(p) for p in _FUNNEL]
+    stages = [d.codes_matching(p) for p in SIGNUP_FUNNEL]
     n = len(b)
     ticks = split_ticks(b.timestamp, n_ticks)
     cap = 1 << int(max(len(ix) for ix in ticks) - 1).bit_length()

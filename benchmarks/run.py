@@ -59,6 +59,8 @@ def main() -> None:
                   else list(sections))
     except ValueError as e:
         ap.error(str(e))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name in picked:
         mod = sections[name]

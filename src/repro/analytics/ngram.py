@@ -18,8 +18,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
+from ..dist.compat import enable_x64
 from ..core.sequences import SessionSequences
 
 

@@ -2,14 +2,13 @@
 
 The paper reconstructs sessions with a Hadoop group-by on
 ``(user_id, session_id)`` followed by a 30-minute-inactivity split. Here the
-same dataflow is a single fused lexicographic sort (``jax.lax.sort`` with
-``num_keys=3`` over user, session, timestamp) followed by segment-boundary
-detection and ``segment_*`` reductions — no shuffle, no reducers, one XLA
-program. The distributed variant (dist/collectives.py) prepends the paper's
-shuffle as an ``all_to_all`` keyed repartition over the mesh ``data`` axis.
+same dataflow is one stable lexicographic sort over (user, session,
+timestamp) (``lexsort_perm``) followed by segment-boundary detection and
+``segment_*`` reductions — no shuffle, no reducers, one XLA program. The
+distributed variant (dist/collectives.py) prepends the paper's shuffle as an ``all_to_all`` keyed repartition over the mesh ``data`` axis.
 
 Identifiers and timestamps are int64; JAX defaults to 32-bit, so the jitted
-pipeline is traced under ``jax.experimental.enable_x64`` — scoped here only,
+pipeline is traced under ``dist.compat.enable_x64`` — scoped here only,
 never leaking into model code.
 """
 from __future__ import annotations
@@ -20,13 +19,47 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+
+from ..dist.compat import enable_x64
 
 # 30 minutes, following standard practice (paper §4.2).
 DEFAULT_GAP_MS = 30 * 60 * 1000
 PAD_CODE = -1  # padding symbol in materialized sequence tensors
 
 _I64_MAX = np.iinfo(np.int64).max
+
+
+def _sort_words(x):
+    """``x`` as order-preserving uint32 words, most significant first:
+    unsigned comparison of the words, in order, is signed comparison of
+    ``x``."""
+    sign = jnp.uint32(0x80000000)
+    if x.dtype == jnp.int64:
+        return [(x >> 32).astype(jnp.int32).astype(jnp.uint32) ^ sign,
+                x.astype(jnp.uint32)]
+    return [x.astype(jnp.int32).astype(jnp.uint32) ^ sign]
+
+
+def lexsort_perm(keys):
+    """The permutation that orders rows by ``keys`` (most significant
+    first), ties kept in input order — the order a stable
+    ``lax.sort(..., num_keys=len(keys))`` gives.
+
+    Built as one stable single-key sort per 32-bit key word, least
+    significant first, inside a ``fori_loop``. The TPU compiler takes
+    minutes over one sort whose comparator spans several int64 keys (its
+    compile time grows with the key width and the row count), while this
+    loop compiles one narrow sort once, whatever the number of keys.
+    """
+    words = jnp.stack([w for k in keys for w in _sort_words(k)])
+    n_words, n = words.shape
+
+    def pass_(j, perm):
+        key = words[n_words - 1 - j][perm]
+        return jax.lax.sort((key, perm), num_keys=1, is_stable=True)[1]
+
+    return jax.lax.fori_loop(0, n_words, pass_,
+                             jnp.arange(n, dtype=jnp.int32))
 
 
 @dataclass
@@ -76,9 +109,10 @@ def mark_duplicate_events(user_id, session_id, timestamp, code, ip, valid):
     file-level dupes, row-level ones survive into the warehouse). Two rows
     are duplicates when all of (user_id, session_id, timestamp, code, ip)
     match; the first occurrence (original order) survives. Implemented as
-    one stable 5-key ``lax.sort`` + neighbour compare + scatter-back through
-    the carried index column — the same sort-based group-by the sessionizer
-    uses, so it composes with it inside a single shard_map stage.
+    one stable 5-key sort (``lexsort_perm``) + neighbour compare +
+    scatter-back through the permutation — the same sort-based group-by the
+    sessionizer uses, so it composes with it inside a single shard_map
+    stage.
     """
     n = user_id.shape[0]
     i64max = jnp.asarray(_I64_MAX, jnp.int64)
@@ -87,11 +121,9 @@ def mark_duplicate_events(user_id, session_id, timestamp, code, ip, valid):
     t = jnp.where(valid, timestamp, i64max)
     c = jnp.where(valid, code.astype(jnp.int64), i64max)
     p = jnp.where(valid, ip.astype(jnp.int64), i64max)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    u, s, t, c, p, idx_s, valid_s = jax.lax.sort(
-        (u, s, t, c, p, idx, valid.astype(jnp.int32)),
-        num_keys=5, is_stable=True)
-    valid_s = valid_s.astype(bool)
+    idx_s = lexsort_perm((u, s, t, c, p))
+    u, s, t, c, p = (x[idx_s] for x in (u, s, t, c, p))
+    valid_s = valid[idx_s]
     same = ((u == jnp.roll(u, 1)) & (s == jnp.roll(s, 1))
             & (t == jnp.roll(t, 1)) & (c == jnp.roll(c, 1))
             & (p == jnp.roll(p, 1)))
@@ -115,11 +147,11 @@ def _sessionize(user_id, session_id, timestamp, code, ip, valid,
     s = jnp.where(valid, session_id, i64max)
     t = jnp.where(valid, timestamp, i64max)
 
-    u, s, t, code_s, ip_s, valid_s = jax.lax.sort(
-        (u, s, t, code.astype(jnp.int32), ip.astype(jnp.int64),
-         valid.astype(jnp.int32)),
-        num_keys=3, is_stable=True)
-    valid_s = valid_s.astype(bool)
+    perm = lexsort_perm((u, s, t))
+    u, s, t = u[perm], s[perm], t[perm]
+    code_s = code.astype(jnp.int32)[perm]
+    ip_s = ip.astype(jnp.int64)[perm]
+    valid_s = valid[perm]
 
     idx = jnp.arange(n, dtype=jnp.int32)
     prev_u = jnp.roll(u, 1)

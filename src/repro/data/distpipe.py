@@ -39,17 +39,16 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..analytics.funnel import build_stage_table, funnel_reach, \
     reach_histogram
-from ..analytics.ngram import dense_ngram_counts, ngram_counts
+from ..analytics.ngram import dense_ngram_counts
 from ..core.sequences import SessionSequences
 from ..core.sessionize import DEFAULT_GAP_MS, mark_duplicate_events, \
     sessionize, _sessionize
 from ..dist.collectives import keyed_all_to_all, shard_of_user
-from ..dist.compat import shard_map, use_mesh
+from ..dist.compat import enable_x64, shard_map, use_mesh
 
 SESSION_FIELDS = ("symbols", "length", "user_id", "session_id", "ip",
                   "start_ts", "duration_s", "num_sessions", "num_events",
@@ -264,9 +263,10 @@ def single_host_pipeline(user_id, session_id, timestamp, code, ip=None,
                    gap_ms=cfg.gap_ms, dedup=cfg.dedup,
                    max_sessions=max_sessions, max_len=cfg.max_len)
     seqs = SessionSequences.from_sessionized(s)
-    keys, counts = ngram_counts(seqs, cfg.ngram_n, cfg.alphabet_size)
-    dense = np.zeros(cfg.alphabet_size ** cfg.ngram_n, np.int64)
-    dense[keys] = counts
+    with enable_x64():
+        dense = np.asarray(dense_ngram_counts(
+            jnp.asarray(seqs.symbols), jnp.asarray(seqs.mask()),
+            cfg.ngram_n, cfg.alphabet_size)).astype(np.int64)
     reach = (None if stages is None else
              funnel_reach(seqs, stages, cfg.alphabet_size))
     return SingleHostResult(sequences=seqs, ngram_counts=dense,

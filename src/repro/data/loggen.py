@@ -75,6 +75,15 @@ STATE_EVENTS: dict[str, list[tuple[str, float]]] = {
     "exit": [("home:timeline::page:unload", 1.0)],
 }
 
+# The signup funnel (paper §5.3) as namespace glob patterns over the event
+# universe above, one per stage, in order.
+SIGNUP_FUNNEL = (
+    "*:signup:landing:form:signup_button:click",
+    "*:signup:form:form:submit_button:submit",
+    "*:signup:follow_suggestions:list:user:follow",
+    "*:signup:complete:page::impression",
+)
+
 # Markov transitions (row-stochastic after normalization).
 def _transition_matrix() -> np.ndarray:
     n = len(STATES)

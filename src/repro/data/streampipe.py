@@ -65,8 +65,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analytics.funnel import build_stage_table, reach_histogram
 from ..analytics.ngram import dense_ngram_counts
@@ -75,7 +74,7 @@ from ..core.sessionize import (DEFAULT_GAP_MS, PAD_CODE, _I64_MAX,
                                _sessionize, closed_prefix_mask,
                                mark_duplicate_events)
 from ..dist.collectives import keyed_all_to_all, shard_of_user
-from ..dist.compat import shard_map, use_mesh
+from ..dist.compat import enable_x64, shard_map, use_mesh
 from .distpipe import DistPipelineConfig, SingleHostResult, \
     single_host_pipeline
 from .store import Store, StoreConfig
@@ -554,9 +553,15 @@ class StreamPipeline(_StreamBase):
             return fn(*args)
 
         self._tick_jit = jax.jit(counted)
-        base = _init_ring_np(cfg)
-        self._ring = {k: np.broadcast_to(v, (self.n_shards,) + v.shape)
-                      .copy() for k, v in base.items()}
+        # The ring starts where every tick leaves it — sharded over the
+        # mesh — so the first tick's input types match every later one's
+        # and the tick traces once.
+        sharded = NamedSharding(mesh, P(cfg.axis))
+        with enable_x64():
+            self._ring = {
+                k: jax.device_put(
+                    np.broadcast_to(v, (self.n_shards,) + v.shape), sharded)
+                for k, v in _init_ring_np(cfg).items()}
 
     def _device_tick(self, ev, wm_prev, wm_new):
         with enable_x64():

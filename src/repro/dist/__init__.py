@@ -28,11 +28,11 @@ Public API by module:
   exchange — identity host-local, all-gather over a mesh axis). The
   multi-stage log pipeline composing these lives in
   ``repro.data.distpipe``.
-* ``compat`` — version-portable wrappers over the jax APIs that moved
-  between 0.4.x and 0.7.x: ``shard_map`` (check_rep/check_vma under one
-  kwarg), ``use_mesh`` (set_mesh / sharding.use_mesh / Mesh ctx),
-  ``make_mesh`` (axis_types when supported), ``abstract_mesh``,
-  ``active_mesh``, ``cost_analysis``.
+* ``compat`` — the jax spellings the tree shares, in one place:
+  ``enable_x64`` (the log tier's scoped 64-bit context), ``shard_map``
+  (replication check off by default), ``use_mesh`` (``jax.set_mesh``),
+  ``make_mesh`` (Auto axis types), ``abstract_mesh``, ``active_mesh``,
+  ``cost_analysis``.
 
 Back-compat shims (kept so pre-PR-1 callers keep working; new code imports
 from ``repro.dist``): ``repro.core.distributed`` re-exports the collectives

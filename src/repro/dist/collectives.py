@@ -31,10 +31,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map, use_mesh
+from .compat import enable_x64, shard_map, use_mesh
 from ..core.sessionize import _sessionize, DEFAULT_GAP_MS
 
 

@@ -1,103 +1,57 @@
-"""Version-portable wrappers over the jax distribution APIs.
+"""The few jax spellings the tree shares, in one place.
 
-The distribution surface moved repeatedly between jax 0.4.x and 0.7.x:
-``shard_map`` graduated from ``jax.experimental`` to ``jax.shard_map`` (and
-its replication check was renamed ``check_rep`` -> ``check_vma``),
-``jax.make_mesh`` grew an ``axis_types`` kwarg, and mesh activation went
-from the ``Mesh`` context manager through ``jax.sharding.use_mesh`` to
-``jax.set_mesh``. Every module in ``repro.dist`` (and everything built on
-it) goes through these wrappers so the rest of the tree never has to care
-which jax it is running on.
+Every module in ``repro.dist`` (and everything built on it) goes through
+these names, so a change of the jax surface is made here once.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
-# --- shard_map -------------------------------------------------------------
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                               # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+def enable_x64():
+    """Context manager tracing and running its body with 64-bit types on.
 
-_SM_PARAMS = inspect.signature(_shard_map).parameters
-_SM_CHECK_KW = "check_vma" if "check_vma" in _SM_PARAMS else "check_rep"
+    The log tier keeps int64 user ids and timestamps end to end; each of
+    its entry points opens this scope itself, so callers never have to.
+    """
+    return jax.enable_x64(True)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` with the replication check under one kwarg name."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SM_CHECK_KW: check})
-
-
-# --- mesh construction -----------------------------------------------------
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-_MAKE_MESH_HAS_TYPES = "axis_types" in inspect.signature(
-    jax.make_mesh).parameters
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _MAKE_MESH_HAS_TYPES and AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types (sharding by rules, not types)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def abstract_mesh(axis_shapes, axis_names):
     """Device-less mesh carrying only (axis_names, shape) — enough for rule
     manipulation (arch_rules / adapt_rules_for_mesh) on meshes larger than
-    the local device count. The constructor changed shape across jax
-    versions; support both."""
-    AbstractMesh = jax.sharding.AbstractMesh
-    params = inspect.signature(AbstractMesh).parameters
-    if "shape_tuple" in params:                     # jax 0.4.x
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
+    the local device count."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
-
-# --- mesh activation -------------------------------------------------------
 
 def use_mesh(mesh):
-    """Context manager activating ``mesh`` for jit / with_sharding_constraint.
-
-    ``with use_mesh(m): ...`` works on every supported jax: ``jax.set_mesh``
-    (>= 0.6.3), ``jax.sharding.use_mesh`` (0.5.x-0.6.x), or the ``Mesh``
-    context manager itself (0.4.x).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` for jit / with_sharding_constraint."""
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a per-device list of dicts, newer ones a single dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, or ``{}`` where the backend gives none."""
+    return compiled.cost_analysis() or {}
 
 
 def active_mesh():
-    """The mesh currently activated (by use_mesh / ``with mesh:``), or None.
+    """The mesh currently activated by ``use_mesh``, or None.
 
-    Works inside jit tracing — the resource env is thread-local and live
-    while the traced function body runs.
+    Works inside jit tracing — the mesh context is live while the traced
+    function body runs.
     """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and not m.empty:
-            return m
-    try:  # pre-0.5: the thread-local resource env set by ``with mesh:``
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m

@@ -8,7 +8,10 @@ over symbols. All tiles live in VMEM; the alphabet axis is the innermost
 sequential grid dim so each symbol tile is read once per alphabet tile.
 
 Grid = (alphabet/block_a, N/block_n); out tile (block_a,) accumulates across
-the sequential n axis.
+the sequential n axis. Both 1-D tiles are 1024 wide: XLA lays a 1-D int32
+array out in tiles of 1024, and a Pallas block of another width gets a
+layout the TPU compiler refuses to bridge. The alphabet and the symbol
+stream are padded up to whole tiles.
 """
 from __future__ import annotations
 
@@ -18,8 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+TILE = 1024  # XLA's tile of a 1-D int32 array on TPU
 
-def _hist_kernel(sym_ref, out_ref, *, block_a: int, num_n_blocks: int):
+
+def _hist_kernel(sym_ref, out_ref, *, block_a: int):
     ia = pl.program_id(0)
     in_ = pl.program_id(1)
 
@@ -36,25 +41,20 @@ def _hist_kernel(sym_ref, out_ref, *, block_a: int, num_n_blocks: int):
 
 
 def histogram_pallas(symbols_flat, *, alphabet_size: int,
-                     block_a: int = 512, block_n: int = 4096,
                      interpret: bool = False):
     """symbols_flat: (N,) int32 with invalid positions = -1."""
     n = symbols_flat.shape[0]
-    block_n = min(block_n, n)
-    pad_n = (-n) % block_n
-    if pad_n:
-        symbols_flat = jnp.pad(symbols_flat, (0, pad_n),
+    pad_n = (-n) % TILE
+    if pad_n or n == 0:
+        symbols_flat = jnp.pad(symbols_flat, (0, pad_n or TILE),
                                constant_values=-1)
-    block_a = min(block_a, alphabet_size)
-    pad_a = (-alphabet_size) % block_a
-    a_total = alphabet_size + pad_a
-    nn = symbols_flat.shape[0] // block_n
+    a_total = alphabet_size + (-alphabet_size) % TILE
 
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, block_a=block_a, num_n_blocks=nn),
-        grid=(a_total // block_a, nn),
-        in_specs=[pl.BlockSpec((block_n,), lambda ia, in_: (in_,))],
-        out_specs=pl.BlockSpec((block_a,), lambda ia, in_: (ia,)),
+        functools.partial(_hist_kernel, block_a=TILE),
+        grid=(a_total // TILE, symbols_flat.shape[0] // TILE),
+        in_specs=[pl.BlockSpec((TILE,), lambda ia, in_: (in_,))],
+        out_specs=pl.BlockSpec((TILE,), lambda ia, in_: (ia,)),
         out_shape=jax.ShapeDtypeStruct((a_total,), jnp.int32),
         interpret=interpret,
     )(symbols_flat)

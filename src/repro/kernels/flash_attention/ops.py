@@ -54,8 +54,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``impl="ref"`` accepts traced kv_len/q_offset (the decode path);
     the Pallas impls require them static (training/prefill shapes).
     Per-row (B,)-shaped kv_len/q_offset — the continuous-batching decode
-    path, Lq == 1 — always routes to the oracle: single-row scores are
-    cheap and the Pallas kernel's masking is scalar-only.
+    path, Lq == 1 — is the oracle's alone: the Pallas kernel's masking is
+    scalar-only, so the Pallas impls refuse it (paged decode has its own
+    kernel, ``paged_decode_attention``).
     ``unroll`` unrolls the blocked impl's k-scan (cost-mode compiles).
     """
     if scale is None:
@@ -68,6 +69,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 "per-row kv_len/q_offset is single-token decode only "
                 f"(got Lq={q.shape[2]}); ragged prefill uses scalar "
                 "kv_len with per-row logit reads instead")
+        if impl in ("pallas", "interpret"):
+            raise ValueError(
+                f"impl={impl!r} masks with a scalar kv_len only; per-row "
+                "decode runs impl='ref' or the paged decode kernel")
         return attention_ref(q, k, v, causal=causal, scale=scale,
                              kv_len=kv_len, q_offset=q_offset)
     if impl == "ref":

@@ -190,7 +190,7 @@ def run_pipeline_cell(shape_name: str, mesh_kind: str,
     and extract the same memory/cost/collective-bytes roofline inputs as the
     model cells. The pipeline has no while loops, so collective bytes from
     the optimized HLO are exact (the keyed all_to_all dominates)."""
-    from jax.experimental import enable_x64
+    from ..dist.compat import enable_x64
     from ..dist.compat import cost_analysis, use_mesh
     from ..dist.mesh import make_production_mesh
 
@@ -273,7 +273,7 @@ def run_stream_cell(shape_name: str, mesh_kind: str,
     """Lower + compile one streaming tick on the production mesh; same
     roofline extraction as the batch pipeline cell. The tick's collectives
     are the keyed all_to_all repartition plus the rollup-delta psums."""
-    from jax.experimental import enable_x64
+    from ..dist.compat import enable_x64
     from ..dist.compat import cost_analysis, use_mesh
     from ..dist.mesh import make_production_mesh
 
@@ -344,7 +344,7 @@ def run_store_cell(shape_name: str, mesh_kind: str,
     """Lower + compile the store compaction kernel; same roofline
     extraction as the other cells (collective bytes are zero — the pass is
     single-host by design, the segments were already user-sharded)."""
-    from jax.experimental import enable_x64
+    from ..dist.compat import enable_x64
     from ..dist.compat import cost_analysis
 
     n_events = STORE_SHAPES[shape_name]

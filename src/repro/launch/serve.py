@@ -55,6 +55,59 @@ def _request_extras(cfg, rng):
     return None
 
 
+BUCKETS = (16, 32, 64)   # prefill widths the continuous path compiles
+
+
+def build_model(arch: str, vocab: int, *, smoke: bool = False,
+                ckpt: str | None = None):
+    """The served model: ``arch``'s FULL (or SMOKE) config over the
+    corpus vocabulary, with random weights from seed 0 unless ``ckpt``
+    holds a checkpoint. Returns ``(api, params)``."""
+    import jax
+    from ..configs import full_config, smoke_config
+    from ..models import get_model
+    from ..train import CheckpointManager, OptConfig, init_opt_state
+
+    cfg = (smoke_config(arch) if smoke else full_config(arch))
+    cfg = cfg.with_(vocab_size=max(vocab, 16), max_cache_len=256)
+    api = get_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    mgr = CheckpointManager(ckpt) if ckpt else None
+    if mgr is not None and mgr.latest_step() is not None:
+        state = dict(params=params,
+                     opt=init_opt_state(params, OptConfig()))
+        state = mgr.restore(state)
+        params = jax.tree.map(jax.numpy.asarray, state["params"])
+        print(f"restored checkpoint step {mgr.latest_step()}")
+    else:
+        print("no checkpoint found — serving untrained weights")
+    return api, params
+
+
+def request_stream(seqs, cfg, n_req: int, slots: int, *,
+                   max_prompt: int = 33, priority_classes: int = 1):
+    """The served request stream: ``(tokens, extra, priority)`` per
+    request. Prompts are 4..``max_prompt`` tokens cut from the packed
+    session rows (``SessionBatchPipeline``), request ``i`` from row
+    ``i % slots`` of step ``i % slots`` — so later requests revisit the
+    rows of earlier ones. Seeded: the same corpus gives the same stream."""
+    from ..data import PipelineConfig, SessionBatchPipeline
+    from ..serve import prompt_lengths
+
+    pipe = SessionBatchPipeline(seqs, PipelineConfig(
+        seq_len=64, global_batch=slots))
+    rng = np.random.default_rng(0)
+    out = []
+    for i in range(n_req):
+        row = pipe.batch_at(0, i % slots)["tokens"]
+        row = np.asarray(row[i % row.shape[0]])
+        n = int(rng.integers(4, min(33, max_prompt + 1)))
+        n = min(n, int(prompt_lengths(row[None])[0]))  # stay on real toks
+        out.append((row[:n], _request_extras(cfg, rng),
+                    int(rng.integers(priority_classes))))
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="behavior-lm-100m")
@@ -130,17 +183,14 @@ def main():
         ap.error("--route affinity requires --paged --prefix-cache (it "
                  "scores replicas by resident prefix chains)")
 
-    import jax
-    from ..configs import full_config, smoke_config
     from ..core import EventDictionary, SessionSequences, sessionize
-    from ..data import (generate, LogGenConfig, SessionBatchPipeline,
-                        PipelineConfig, lm_vocab_size, NUM_SPECIALS)
-    from ..models import get_model
-    from ..train import CheckpointManager, OptConfig, init_opt_state
+    from ..data import generate, LogGenConfig, lm_vocab_size, NUM_SPECIALS
     from ..serve import (Server, ServeConfig, ContinuousScheduler,
-                         SchedulerConfig, ServeMetrics, prompt_lengths,
-                         ReplicaRouter, FleetConfig)
+                         SchedulerConfig, ServeMetrics, ReplicaRouter,
+                         FleetConfig)
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     log = generate(LogGenConfig(n_users=400, seed=0))
     b = log.batch
     d = EventDictionary.build(b.table, b.name_id)
@@ -148,30 +198,17 @@ def main():
     s = sessionize(b.user_id, b.session_id, b.timestamp, codes,
                    b.ip.astype(np.int64), max_sessions=len(b), max_len=2048)
     seqs = SessionSequences.from_sessionized(s)
-    vocab = lm_vocab_size(d.alphabet_size)
-
-    cfg = (smoke_config(args.arch) if args.smoke else full_config(args.arch))
-    cfg = cfg.with_(vocab_size=max(vocab, 16), max_cache_len=256)
-    api = get_model(cfg)
-    params = api.init(jax.random.PRNGKey(0))
-    mgr = CheckpointManager(args.ckpt)
-    if mgr.latest_step() is not None:
-        state = dict(params=params,
-                     opt=init_opt_state(params, OptConfig()))
-        state = mgr.restore(state)
-        params = jax.tree.map(jax.numpy.asarray, state["params"])
-        print(f"restored checkpoint step {mgr.latest_step()}")
-    else:
-        print("no checkpoint found — serving untrained weights")
-
+    api, params = build_model(args.arch, lm_vocab_size(d.alphabet_size),
+                              smoke=args.smoke, ckpt=args.ckpt)
+    cfg = api.cfg
     slots = max(args.slots, 1)
-    pipe = SessionBatchPipeline(seqs, PipelineConfig(
-        seq_len=64, global_batch=slots))
-    rng = np.random.default_rng(0)
 
     if args.batch:
+        from ..data import PipelineConfig, SessionBatchPipeline
+        pipe = SessionBatchPipeline(seqs, PipelineConfig(
+            seq_len=64, global_batch=slots))
         prompts = pipe.batch_at(0, 0)["tokens"][:slots, :32]
-        extra = _request_extras(cfg, rng)
+        extra = _request_extras(cfg, np.random.default_rng(0))
         if extra is not None:
             extra = {k: np.stack([v] * prompts.shape[0])
                      for k, v in extra.items()}
@@ -191,7 +228,7 @@ def main():
     # independent replicas behind the ReplicaRouter (same surface).
     n_req = args.requests or 3 * slots * args.replicas
     scfg = SchedulerConfig(
-        batch=slots, buckets=(16, 32, 64),
+        batch=slots, buckets=BUCKETS,
         max_new_tokens=args.max_new_tokens,
         temperature=args.temperature, paged=args.paged,
         block_size=args.block_size,
@@ -206,15 +243,11 @@ def main():
     # over-commit caps the prompt so a preempted request's re-prefill
     # (prompt + generated) always fits the largest compiled bucket
     max_prompt = 33 if args.overcommit <= 1.0 else \
-        max(4, 64 - args.max_new_tokens + 1)
-    rids = []
-    for i in range(n_req):
-        row = pipe.batch_at(0, i % slots)["tokens"]
-        row = np.asarray(row[i % row.shape[0]])
-        n = int(rng.integers(4, min(33, max_prompt + 1)))
-        n = min(n, int(prompt_lengths(row[None])[0]))  # stay on real toks
-        rids.append(sched.submit(row[:n], extra=_request_extras(cfg, rng),
-                                 priority=int(rng.integers(args.priority))))
+        max(4, max(BUCKETS) - args.max_new_tokens + 1)
+    rids = [sched.submit(toks, extra=extra, priority=prio)
+            for toks, extra, prio in request_stream(
+                seqs, cfg, n_req, slots, max_prompt=max_prompt,
+                priority_classes=args.priority)]
     outs = sched.run()
     for rid in rids[:slots]:
         names = _decode_names(outs[rid], d, NUM_SPECIALS)

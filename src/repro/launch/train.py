@@ -46,7 +46,9 @@ def main():
     from ..dist.sharding import ShardingRules, adapt_rules_for_mesh
     from ..models import get_model
     from ..train import OptConfig, Trainer, TrainerConfig
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     log = generate(LogGenConfig(n_users=args.users, seed=0))
     b = log.batch
     d = EventDictionary.build(b.table, b.name_id)

@@ -47,6 +47,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..dist.sharding import tree_shardings
 from ..models.registry import ModelApi
@@ -132,20 +133,21 @@ class DecodeState:
         return jax.tree.map(grow, self._row_shapes, self._axes)
 
     def _place(self, state):
-        """Best-effort ``repro.dist`` placement: the family's declared
-        state axes when the tree matches, else leave unplaced (host-local
-        test meshes degrade to replicated either way)."""
+        """``repro.dist`` placement: the family's declared state axes when
+        the tree matches, else replicated. Either way every leaf lives on
+        the mesh, so the jitted insert and decode step see the same input
+        types on the first call as on every later one (an array made off
+        the mesh has a different type, and would retrace them)."""
         if self.mesh is None:
             return state
         axes_fn = self.api.caps.state_axes
-        if axes_fn is None:
-            return state
-        try:
-            shardings = tree_shardings(axes_fn(self.api.cfg),
-                                       self.api.rules, self.mesh)
-            return jax.device_put(state, shardings)
-        except ValueError:
-            return state
+        if axes_fn is not None:
+            try:
+                return jax.device_put(state, tree_shardings(
+                    axes_fn(self.api.cfg), self.api.rules, self.mesh))
+            except ValueError:
+                pass
+        return jax.device_put(state, NamedSharding(self.mesh, P()))
 
     def init(self, batch: int, budget: int) -> None:
         self.batch = batch
@@ -359,7 +361,7 @@ class PagedKVState(DenseKVState):
             return jax.device_put(
                 state, tree_shardings(axes, self.api.rules, self.mesh))
         except ValueError:
-            return state
+            return jax.device_put(state, NamedSharding(self.mesh, P()))
 
     # -- admission ---------------------------------------------------------
 

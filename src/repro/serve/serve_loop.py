@@ -214,3 +214,20 @@ class Server:
         while len(out) < n:          # EOS-frozen tail after short-circuit
             out.append(np.full((b,), EOS_ID, np.int32))
         return np.stack(out, axis=1)
+
+    def score_batch(self, prompts: np.ndarray, tokens: np.ndarray):
+        """The fixed-batch path teacher-forced on ``tokens`` (B, T): the
+        (B, T, vocab) float32 logits that token ``t`` of each row is drawn
+        from, given the prompt and that row's tokens before ``t`` — the
+        same prefill and decode programs ``generate_batch`` runs."""
+        prompts = np.asarray(prompts, np.int32)
+        tokens = np.asarray(tokens, np.int32)
+        logits, state, index = self._prefill(self.params, dict(
+            tokens=jnp.asarray(prompts),
+            lengths=jnp.asarray(prompt_lengths(prompts))))
+        out = [np.asarray(logits, np.float32)]
+        for t in range(tokens.shape[1] - 1):
+            logits, state = self._decode(self.params, jnp.asarray(tokens[:, t]),
+                                         state, index + t)
+            out.append(np.asarray(logits, np.float32))
+        return np.stack(out, axis=1)

@@ -9,16 +9,6 @@ import pytest
 # strictly dryrun.py's (subprocess tests set their own XLA_FLAGS).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# The paper's signup funnel (§5.3), as namespace glob patterns over the
-# loggen event universe — shared by the batch and streaming equivalence
-# tests so both see the identical stage spec.
-LOGGEN_FUNNEL = [
-    "*:signup:landing:form:signup_button:click",
-    "*:signup:form:form:submit_button:submit",
-    "*:signup:follow_suggestions:list:user:follow",
-    "*:signup:complete:page::impression",
-]
-
 
 @pytest.fixture(scope="session", params=[dict(n_users=250, seed=123)],
                 ids=lambda p: f"loggen-u{p['n_users']}-s{p['seed']}")
@@ -27,10 +17,12 @@ def loggen_corpus(request):
 
     Session-scoped and parametrized so the batch (test_distpipe) and
     streaming (test_streampipe) equivalence tests consume byte-identical
-    inputs without regenerating the corpus per test.
+    inputs — the paper's signup funnel among them — without regenerating
+    the corpus per test.
     """
     from repro.core import EventDictionary
     from repro.data import LogGenConfig, generate
+    from repro.data.loggen import SIGNUP_FUNNEL
     p = request.param
     log = generate(LogGenConfig(n_users=p["n_users"], seed=p["seed"],
                                 signup_fraction=0.25))
@@ -41,16 +33,5 @@ def loggen_corpus(request):
         user_id=b.user_id, session_id=b.session_id, timestamp=b.timestamp,
         code=codes, ip=b.ip.astype(np.int64),
         alphabet_size=d.alphabet_size, dictionary=d,
-        stages=[d.codes_matching(pat) for pat in LOGGEN_FUNNEL],
+        stages=[d.codes_matching(pat) for pat in SIGNUP_FUNNEL],
         n_events=len(b))
-
-# The container image has no ``hypothesis``; alias in the deterministic
-# mini-implementation so the property tests still run (the real package
-# wins whenever it is importable, e.g. in CI).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_fallback
-    sys.modules["hypothesis"] = _hypothesis_fallback
-    sys.modules["hypothesis.strategies"] = _hypothesis_fallback.strategies

@@ -47,7 +47,7 @@ def _events(n, seed, n_users=150, n_dupes=0):
 # ---------------------------------------------------------------------------
 
 def test_mark_duplicates_matches_oracle():
-    from jax.experimental import enable_x64
+    from repro.dist.compat import enable_x64
     import jax.numpy as jnp
     from repro.core.sessionize import mark_duplicate_events
     from repro.core.oracle import dedup_events_oracle
@@ -82,7 +82,7 @@ def test_sessionize_dedup_kwarg():
 
 def test_dense_ngram_matches_sparse():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.dist.compat import enable_x64
     from repro.analytics.ngram import dense_ngram_counts, ngram_counts
     from repro.core import SessionSequences, sessionize
     user, sess, ts, code, ip = _events(2048, seed=11)

@@ -1,5 +1,5 @@
 import pytest
-from hypothesis import given, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import namespace as ns
 
@@ -31,6 +31,7 @@ def test_invalid_names_rejected(bad):
         ns.parse(bad)
 
 
+@settings(deadline=None)
 @given(st.lists(TOKEN, min_size=6, max_size=6))
 def test_roundtrip_property(tokens):
     name = ":".join(tokens)

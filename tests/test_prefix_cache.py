@@ -165,7 +165,7 @@ def test_find_extension_matches_leading_tokens_under_parent():
 # property tests: random take/share/free sequences vs a shadow allocator
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.lists(st.integers(min_value=0, max_value=2**31 - 1),
                 min_size=1, max_size=80))
 def test_pool_random_sequences_keep_invariants(ops):
@@ -202,7 +202,7 @@ def test_pool_random_sequences_keep_invariants(ops):
                 pool.free([blk])
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(min_value=0, max_value=2**31 - 1))
 def test_pool_registration_follows_residency(seed):
     rnd = np.random.default_rng(seed)

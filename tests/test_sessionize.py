@@ -112,3 +112,25 @@ def test_unordered_input_ok():
     assert np.array_equal(a.user_id, b.user_id)
     assert np.array_equal(a.length, b.length)
     assert np.array_equal(a.duration_s, b.duration_s)
+
+
+@given(st.integers(0, 2**31 - 1), st.integers(1, 200))
+@settings(max_examples=25, deadline=None)
+def test_lexsort_perm_is_the_stable_lexicographic_order(seed, n):
+    """``lexsort_perm`` (one stable 32-bit pass per key word) gives exactly
+    ``np.lexsort``'s stable order — negative, extreme and tied int64 keys
+    and an int32 key included."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.sessionize import lexsort_perm
+    from repro.dist.compat import enable_x64
+    rng = np.random.default_rng(seed)
+    pool = np.array([np.iinfo(np.int64).min, -(1 << 32), -1, 0, 1,
+                     (1 << 32) - 1, 1 << 32, np.iinfo(np.int64).max])
+    a = rng.choice(pool, n)
+    b = rng.integers(-3, 3, n).astype(np.int64) << rng.integers(0, 40)
+    c = rng.integers(-3, 3, n).astype(np.int32)
+    with enable_x64():
+        got = np.asarray(jax.jit(lexsort_perm)(
+            (jnp.asarray(a), jnp.asarray(b), jnp.asarray(c))))
+    assert np.array_equal(got, np.lexsort((c, b, a)))

@@ -356,7 +356,7 @@ def test_save_load_round_trip(tmp_path):
 
 
 def test_user_shard_mask_matches_jax_sharding():
-    from jax.experimental import enable_x64
+    from repro.dist.compat import enable_x64
     from repro.dist.collectives import shard_of_user
     uids = np.arange(0, 5000, 37, dtype=np.int64) * 7919
     with enable_x64():
