@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over the cell's devices (device trace)."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t is None:
+        return None
+    return 1.0 - t["busy_s"] / t["window_s"]
