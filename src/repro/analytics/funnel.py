@@ -55,6 +55,8 @@ def _deepest_stage(symbols, mask, stage_table, n_stages):
 
 
 @functools.partial(jax.jit, static_argnames=("n_stages",))
+@jax.named_scope("rollup")
+@jax.named_scope("funnel")
 def reach_histogram(symbols, mask, stage_table, n_stages):
     """(n_stages,) int32 reach counts — the shard-local half of the
     distributed funnel rollup.

@@ -52,6 +52,8 @@ def _sorted_unique_counts(keys_flat):
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet_size"))
+@jax.named_scope("rollup")
+@jax.named_scope("ngram")
 def dense_ngram_counts(symbols, mask, n, alphabet_size):
     """Dense (alphabet_size**n,) count vector of order-n grams — the
     shard-local half of the distributed rollup.

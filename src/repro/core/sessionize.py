@@ -40,6 +40,7 @@ def _sort_words(x):
     return [x.astype(jnp.int32).astype(jnp.uint32) ^ sign]
 
 
+@jax.named_scope("sort")
 def lexsort_perm(keys):
     """The permutation that orders rows by ``keys`` (most significant
     first), ties kept in input order — the order a stable
@@ -100,6 +101,7 @@ class Sessionized:
 
 
 @jax.jit
+@jax.named_scope("dedup")
 def mark_duplicate_events(user_id, session_id, timestamp, code, ip, valid):
     """Within-user exact-duplicate removal — returns the validity mask with
     retry duplicates cleared.
@@ -136,6 +138,7 @@ def mark_duplicate_events(user_id, session_id, timestamp, code, ip, valid):
 
 @functools.partial(jax.jit, static_argnames=("gap_ms", "max_sessions",
                                              "max_len", "with_event_grids"))
+@jax.named_scope("sessionize")
 def _sessionize(user_id, session_id, timestamp, code, ip, valid,
                 *, gap_ms: int, max_sessions: int, max_len: int,
                 with_event_grids: bool = False):
@@ -175,24 +178,30 @@ def _sessionize(user_id, session_id, timestamp, code, ip, valid,
     num_events = jnp.sum(valid_s.astype(jnp.int32))
 
     nseg = max_sessions + 1  # + drop bucket
-    ones = jnp.ones_like(seg)
-    length = jax.ops.segment_sum(ones, seg, num_segments=nseg)
-    start_idx = jax.ops.segment_min(idx, seg, num_segments=nseg)
-    start_ts = jax.ops.segment_min(t, seg, num_segments=nseg)
-    end_ts = jax.ops.segment_max(
-        jnp.where(valid_s, t, jnp.asarray(0, jnp.int64)), seg, num_segments=nseg)
-    seg_user = jax.ops.segment_max(
-        jnp.where(valid_s, u, jnp.asarray(-1, jnp.int64)), seg, num_segments=nseg)
-    seg_sess = jax.ops.segment_max(
-        jnp.where(valid_s, s, jnp.asarray(-1, jnp.int64)), seg, num_segments=nseg)
-    seg_ip = jax.ops.segment_max(
-        jnp.where(valid_s, ip_s, jnp.asarray(-1, jnp.int64)), seg, num_segments=nseg)
+    with jax.named_scope("segments"):
+        ones = jnp.ones_like(seg)
+        length = jax.ops.segment_sum(ones, seg, num_segments=nseg)
+        start_idx = jax.ops.segment_min(idx, seg, num_segments=nseg)
+        start_ts = jax.ops.segment_min(t, seg, num_segments=nseg)
+        end_ts = jax.ops.segment_max(
+            jnp.where(valid_s, t, jnp.asarray(0, jnp.int64)), seg,
+            num_segments=nseg)
+        seg_user = jax.ops.segment_max(
+            jnp.where(valid_s, u, jnp.asarray(-1, jnp.int64)), seg,
+            num_segments=nseg)
+        seg_sess = jax.ops.segment_max(
+            jnp.where(valid_s, s, jnp.asarray(-1, jnp.int64)), seg,
+            num_segments=nseg)
+        seg_ip = jax.ops.segment_max(
+            jnp.where(valid_s, ip_s, jnp.asarray(-1, jnp.int64)), seg,
+            num_segments=nseg)
 
     pos = idx - start_idx[seg]
     # Scatter codes into the padded (sessions, time) tensor; OOB rows/cols
     # (drop bucket, beyond max_len) are dropped by mode='drop'.
-    symbols = jnp.full((max_sessions, max_len), PAD_CODE, jnp.int32)
-    symbols = symbols.at[seg, pos].set(code_s, mode="drop")
+    with jax.named_scope("grid"):
+        symbols = jnp.full((max_sessions, max_len), PAD_CODE, jnp.int32)
+        symbols = symbols.at[seg, pos].set(code_s, mode="drop")
 
     duration_s = ((end_ts[:max_sessions] - start_ts[:max_sessions])
                   // 1000).astype(jnp.int32)
@@ -202,13 +211,14 @@ def _sessionize(user_id, session_id, timestamp, code, ip, valid,
         # Per-event grids aligned with ``symbols`` (streaming ring state:
         # data/streampipe.py re-sorts open sessions with new events each
         # tick, so it must keep every stored event's timestamp and ip).
-        ts_grid = jnp.zeros((max_sessions, max_len), jnp.int64)
-        ip_grid = jnp.zeros((max_sessions, max_len), jnp.int64)
-        extras = dict(
-            event_ts=ts_grid.at[seg, pos].set(t, mode="drop"),
-            event_ip=ip_grid.at[seg, pos].set(ip_s, mode="drop"),
-            end_ts=jnp.where(empty, 0, jnp.asarray(end_ts[:max_sessions])),
-        )
+        with jax.named_scope("grid"):
+            ts_grid = jnp.zeros((max_sessions, max_len), jnp.int64)
+            ip_grid = jnp.zeros((max_sessions, max_len), jnp.int64)
+            extras = dict(
+                event_ts=ts_grid.at[seg, pos].set(t, mode="drop"),
+                event_ip=ip_grid.at[seg, pos].set(ip_s, mode="drop"))
+        extras["end_ts"] = jnp.where(empty, 0,
+                                     jnp.asarray(end_ts[:max_sessions]))
     return dict(
         **extras,
         symbols=symbols,

@@ -45,6 +45,7 @@ from ..analytics.funnel import build_stage_table, funnel_reach, \
     reach_histogram
 from ..analytics.ngram import dense_ngram_counts
 from ..core.sequences import SessionSequences
+from ..core.spans import span
 from ..core.sessionize import DEFAULT_GAP_MS, mark_duplicate_events, \
     sessionize, _sessionize
 from ..dist.collectives import keyed_all_to_all, shard_of_user
@@ -99,12 +100,14 @@ class DistPipelineResult:
         """Gather the sharded sessions into one host-side relation (shard
         order, per-shard (user, session, start) order)."""
         ns = self.sessions["num_sessions"]
-        parts = {name: [self.sessions[name][sh, : int(ns[sh])]
-                        for sh in range(len(ns))]
-                 for name in ("symbols", "length", "user_id", "session_id",
-                              "ip", "start_ts", "duration_s")}
-        return SessionSequences(
-            **{k: np.concatenate(v) for k, v in parts.items()})
+        with span("distpipe.gather", sessions=int(ns.sum())):
+            parts = {name: [self.sessions[name][sh, : int(ns[sh])]
+                            for sh in range(len(ns))]
+                     for name in ("symbols", "length", "user_id",
+                                  "session_id", "ip", "start_ts",
+                                  "duration_s")}
+            return SessionSequences(
+                **{k: np.concatenate(v) for k, v in parts.items()})
 
 
 def build_pipeline_fn(mesh: Mesh, cfg: DistPipelineConfig, n_stages: int):
@@ -206,24 +209,32 @@ class DistributedPipeline:
 
         table = (np.zeros((0, cfg.alphabet_size), bool)
                  if self.stage_table is None else self.stage_table)
-        with enable_x64():
-            with use_mesh(self.mesh):
-                sess, grams, reach, dropped = self._jitted(
-                    jnp.asarray(col(user_id, np.int64)),
-                    jnp.asarray(col(session_id, np.int64)),
-                    jnp.asarray(col(timestamp, np.int64)),
-                    jnp.asarray(col(code, np.int32)),
-                    jnp.asarray(col(ip, np.int64)),
-                    jnp.asarray(col(valid, bool)),
-                    jnp.asarray(table))
-        sess = {k: np.asarray(v) for k, v in sess.items()}
-        return DistPipelineResult(
-            sessions=sess,
-            ngram_counts=np.asarray(grams).astype(np.int64),
-            funnel_reach=(None if self.stage_table is None else
-                          [(j, int(c)) for j, c in enumerate(np.asarray(reach))]),
-            dropped=int(np.asarray(dropped)[0]),
-            truncated=bool(np.asarray(sess["truncated"]).any()))
+        with span("distpipe.call", events=n):
+            with enable_x64():
+                with span("distpipe.put"):
+                    args = (jnp.asarray(col(user_id, np.int64)),
+                            jnp.asarray(col(session_id, np.int64)),
+                            jnp.asarray(col(timestamp, np.int64)),
+                            jnp.asarray(col(code, np.int32)),
+                            jnp.asarray(col(ip, np.int64)),
+                            jnp.asarray(col(valid, bool)),
+                            jnp.asarray(table))
+                with use_mesh(self.mesh), span("distpipe.dispatch"):
+                    out = self._jitted(*args)
+            with span("distpipe.wait"):
+                sess, grams, reach, dropped = jax.block_until_ready(out)
+            with span("distpipe.pull", bytes=sum(
+                    x.nbytes for x in jax.tree.leaves(out))):
+                sess = {k: np.asarray(v) for k, v in sess.items()}
+                grams, reach = np.asarray(grams), np.asarray(reach)
+                dropped = np.asarray(dropped)
+            return DistPipelineResult(
+                sessions=sess,
+                ngram_counts=grams.astype(np.int64),
+                funnel_reach=(None if self.stage_table is None else
+                              [(j, int(c)) for j, c in enumerate(reach)]),
+                dropped=int(dropped[0]),
+                truncated=bool(sess["truncated"].any()))
 
 
 def make_distributed_pipeline(mesh: Mesh, cfg: DistPipelineConfig,

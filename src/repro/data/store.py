@@ -53,6 +53,7 @@ import numpy as np
 
 from ..core import varint
 from ..core.sequences import SessionSequences
+from ..core.spans import span
 from ..core.sessionize import (DEFAULT_GAP_MS, PAD_CODE, closed_prefix_mask,
                                sessionize)
 
@@ -183,9 +184,19 @@ def encode_session_segment(seg_id: int, seqs: SessionSequences, *,
     covers every event of every session (duration is floor-seconds), so
     time pruning can never drop a matching segment.
     """
-    n = len(seqs)
-    payloads = [varint.encode_session(seqs.session_symbols(j))
-                for j in range(n)]
+    payloads = _session_payloads(seqs)
+    blob, col_bytes = _session_blob(seqs, payloads)
+    return _session_segment(seg_id, seqs, blob, col_bytes, user_shards)
+
+
+def _session_payloads(seqs: SessionSequences) -> list[bytes]:
+    return [varint.encode_session(seqs.session_symbols(j))
+            for j in range(len(seqs))]
+
+
+def _session_blob(seqs: SessionSequences, payloads: list[bytes]
+                  ) -> tuple[bytes, dict[str, int]]:
+    """The varint metadata columns, then the payloads, as one blob."""
     payload_len = np.array([len(p) for p in payloads], np.int64)
     blocks = dict(
         start_ts=varint.encode_ivarint(
@@ -201,6 +212,14 @@ def encode_session_segment(seg_id: int, seqs: SessionSequences, *,
     blob = b"".join(blocks[k] for k in SESSION_COLS) + b"".join(payloads)
     col_bytes = {k: len(v) for k, v in blocks.items()}
     col_bytes["payload"] = int(payload_len.sum())
+    return blob, col_bytes
+
+
+def _session_segment(seg_id: int, seqs: SessionSequences, blob: bytes,
+                     col_bytes: dict[str, int], user_shards: int) -> Segment:
+    """The segment around an encoded blob, with the metadata ``scan``
+    prunes on."""
+    n = len(seqs)
     start = np.asarray(seqs.start_ts, np.int64)
     hi = start + (np.asarray(seqs.duration_s, np.int64) + 1) * 1000
     mask = seqs.mask()
@@ -383,12 +402,20 @@ class Store:
     def append_sessions(self, seqs: SessionSequences) -> Segment:
         """Already-materialized sessions (the streaming tier's closed
         blocks) -> one immutable session segment."""
-        seg = encode_session_segment(self._take_id(), seqs,
-                                     user_shards=self.cfg.user_shards)
-        self.segments.append(seg)
-        self.events_appended += seg.n_events
-        self._enforce_residency()
-        return seg
+        events = int(np.asarray(seqs.length, np.int64).sum())
+        with span("store.append_sessions", sessions=len(seqs),
+                  events=events):
+            with span("store.encode_payloads"):
+                payloads = _session_payloads(seqs)
+            with span("store.encode_columns"):
+                blob, col_bytes = _session_blob(seqs, payloads)
+            with span("store.index"):
+                seg = _session_segment(self._take_id(), seqs, blob,
+                                       col_bytes, self.cfg.user_shards)
+                self.segments.append(seg)
+                self.events_appended += seg.n_events
+                self._enforce_residency()
+            return seg
 
     # -- compaction --------------------------------------------------------
 

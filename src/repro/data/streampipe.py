@@ -70,6 +70,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..analytics.funnel import build_stage_table, reach_histogram
 from ..analytics.ngram import dense_ngram_counts
 from ..core.sequences import SessionSequences
+from ..core.spans import span
 from ..core.sessionize import (DEFAULT_GAP_MS, PAD_CODE, _I64_MAX,
                                _sessionize, closed_prefix_mask,
                                mark_duplicate_events)
@@ -212,18 +213,19 @@ def _tick_core(ring, ev, wm_prev, wm_new, stage_tab, *, cfg: StreamConfig,
     # Flatten the ring back into event rows. Stored events carry their full
     # (user, session, ts, code, ip) key, so dedup and re-sort against the
     # new events are exact.
-    stored = jnp.minimum(ring["length"], L)
-    col = jnp.arange(L, dtype=jnp.int32)
-    r_valid = (ring["valid"][:, None] & (col[None, :] < stored[:, None]))
-    r_user = jnp.broadcast_to(ring["user_id"][:, None], (O, L))
-    r_sess = jnp.broadcast_to(ring["session_id"][:, None], (O, L))
+    with jax.named_scope("ring"):
+        stored = jnp.minimum(ring["length"], L)
+        col = jnp.arange(L, dtype=jnp.int32)
+        r_valid = (ring["valid"][:, None] & (col[None, :] < stored[:, None]))
+        r_user = jnp.broadcast_to(ring["user_id"][:, None], (O, L))
+        r_sess = jnp.broadcast_to(ring["session_id"][:, None], (O, L))
 
-    u = jnp.concatenate([r_user.reshape(-1), ev["user_id"]])
-    s = jnp.concatenate([r_sess.reshape(-1), ev["session_id"]])
-    t = jnp.concatenate([ring["event_ts"].reshape(-1), ev["timestamp"]])
-    c = jnp.concatenate([ring["symbols"].reshape(-1), ev["code"]])
-    i = jnp.concatenate([ring["event_ip"].reshape(-1), ev["ip"]])
-    v = jnp.concatenate([r_valid.reshape(-1), ev_valid])
+        u = jnp.concatenate([r_user.reshape(-1), ev["user_id"]])
+        s = jnp.concatenate([r_sess.reshape(-1), ev["session_id"]])
+        t = jnp.concatenate([ring["event_ts"].reshape(-1), ev["timestamp"]])
+        c = jnp.concatenate([ring["symbols"].reshape(-1), ev["code"]])
+        i = jnp.concatenate([ring["event_ip"].reshape(-1), ev["ip"]])
+        v = jnp.concatenate([r_valid.reshape(-1), ev_valid])
     if cfg.dedup:
         # Ring rows precede tick rows, so a retry duplicate of a stored
         # event is the copy that dies — ring contents stay stable.
@@ -233,15 +235,17 @@ def _tick_core(ring, ev, wm_prev, wm_new, stage_tab, *, cfg: StreamConfig,
                        max_sessions=s_cap, max_len=L, with_event_grids=True)
 
     row = jnp.arange(s_cap, dtype=jnp.int32)
-    nonempty = row < sess["num_sessions"]
-    # Closed iff no future event can join: any extender has
-    # ts <= end_ts + gap, and future arrivals have ts >= watermark.
-    closed = nonempty & (sess["end_ts"] + cfg.gap_ms < wm_new)
-    open_m = nonempty & ~closed
+    with jax.named_scope("ring"):
+        nonempty = row < sess["num_sessions"]
+        # Closed iff no future event can join: any extender has
+        # ts <= end_ts + gap, and future arrivals have ts >= watermark.
+        closed = nonempty & (sess["end_ts"] + cfg.gap_ms < wm_new)
+        open_m = nonempty & ~closed
 
-    perm_c = jnp.argsort(~closed, stable=True)  # closed rows first
-    cb = {k: sess[k][perm_c] for k in _PER_ROW_FIELDS}
-    n_closed = jnp.sum(closed.astype(jnp.int32))
+        with jax.named_scope("sort"):
+            perm_c = jnp.argsort(~closed, stable=True)  # closed rows first
+        cb = {k: sess[k][perm_c] for k in _PER_ROW_FIELDS}
+        n_closed = jnp.sum(closed.astype(jnp.int32))
 
     c_stored = jnp.minimum(cb["length"], L)
     c_mask = ((row[:, None] < n_closed)
@@ -253,19 +257,21 @@ def _tick_core(ring, ev, wm_prev, wm_new, stage_tab, *, cfg: StreamConfig,
     else:
         reach = jnp.zeros((0,), jnp.int32)
 
-    perm_o = jnp.argsort(~open_m, stable=True)  # open rows first
-    ob = {k: sess[k][perm_o] for k in _PER_ROW_FIELDS}
-    n_open = jnp.sum(open_m.astype(jnp.int32))
-    keep = jnp.arange(O, dtype=jnp.int32) < jnp.minimum(n_open, O)
-    new_ring = dict(
-        user_id=jnp.where(keep, ob["user_id"][:O], -1),
-        session_id=jnp.where(keep, ob["session_id"][:O], -1),
-        length=jnp.where(keep, ob["length"][:O], 0),
-        symbols=jnp.where(keep[:, None], ob["symbols"][:O], PAD_CODE),
-        event_ts=jnp.where(keep[:, None], ob["event_ts"][:O], 0),
-        event_ip=jnp.where(keep[:, None], ob["event_ip"][:O], 0),
-        valid=keep,
-    )
+    with jax.named_scope("ring"):
+        with jax.named_scope("sort"):
+            perm_o = jnp.argsort(~open_m, stable=True)  # open rows first
+        ob = {k: sess[k][perm_o] for k in _PER_ROW_FIELDS}
+        n_open = jnp.sum(open_m.astype(jnp.int32))
+        keep = jnp.arange(O, dtype=jnp.int32) < jnp.minimum(n_open, O)
+        new_ring = dict(
+            user_id=jnp.where(keep, ob["user_id"][:O], -1),
+            session_id=jnp.where(keep, ob["session_id"][:O], -1),
+            length=jnp.where(keep, ob["length"][:O], 0),
+            symbols=jnp.where(keep[:, None], ob["symbols"][:O], PAD_CODE),
+            event_ts=jnp.where(keep[:, None], ob["event_ts"][:O], 0),
+            event_ip=jnp.where(keep[:, None], ob["event_ip"][:O], 0),
+            valid=keep,
+        )
     # Ring overflow: open sessions ranked past capacity are dropped whole
     # (deterministic — sessionizer sort order), counted never silent.
     over = (row >= O) & (row < n_open)
@@ -293,8 +299,11 @@ def _single_host_tick(cfg: StreamConfig, n_stages: int):
 
     def fn(ring, ev, wm_prev, wm_new, stage_tab):
         counter["tick"] += 1  # runs at trace time only
-        return _tick_core(ring, ev, wm_prev, wm_new, stage_tab,
-                          cfg=cfg, n_stages=n_stages)
+        ring, cb, n_closed, grams, reach, counters = _tick_core(
+            ring, ev, wm_prev, wm_new, stage_tab, cfg=cfg, n_stages=n_stages)
+        # one shard, laid out as the mesh tick lays out its outputs
+        return (ring, {k: v[None] for k, v in cb.items()}, n_closed[None],
+                grams, reach, counters)
 
     return jax.jit(fn), counter
 
@@ -368,8 +377,9 @@ class _StreamBase:
                             build_stage_table(stages, cfg.alphabet_size))
         self.n_stages = (0 if self.stage_table is None
                          else len(self.stage_table))
-        self._table = (np.zeros((0, cfg.alphabet_size), bool)
-                       if self.stage_table is None else self.stage_table)
+        self._stage_tab = jnp.asarray(
+            np.zeros((0, cfg.alphabet_size), bool)
+            if self.stage_table is None else self.stage_table)
         self.watermark = WATERMARK_MIN
         self.max_ts_seen = WATERMARK_MIN
         self.ngram_totals = np.zeros(cfg.alphabet_size ** cfg.ngram_n,
@@ -391,8 +401,10 @@ class _StreamBase:
 
     # -- subclass surface --------------------------------------------------
 
-    def _device_tick(self, ev: dict[str, np.ndarray], wm_prev: int,
-                     wm_new: int):
+    def _device_tick(self, ev: dict[str, jax.Array], wm_prev: jax.Array,
+                     wm_new: jax.Array):
+        """Dispatch the tick on the device-resident padded events, keep
+        the new ring, and return ``self._pull`` of the other outputs."""
         raise NotImplementedError
 
     # -- the tick ----------------------------------------------------------
@@ -414,44 +426,51 @@ class _StreamBase:
             raise ValueError(
                 f"tick has {n} events > tick_capacity={cfg.tick_capacity}; "
                 "split the tick or build the stream with a larger capacity")
-        ts = np.asarray(timestamp, np.int64)
-        wm_prev = self.watermark
-        if n:
-            self.max_ts_seen = max(self.max_ts_seen, int(ts.max()))
-        if watermark is not None:
-            wm_new = max(wm_prev, int(watermark))
-        elif n:
-            wm_new = max(wm_prev, int(ts.max()) - cfg.allowed_lateness_ms)
-        else:
-            wm_new = wm_prev
-        accepted = (ts >= wm_prev) if n else np.zeros(0, bool)
+        flush = watermark is not None and watermark >= WATERMARK_MAX
+        with span("streampipe.tick", events=n, flush=int(flush)):
+            ts = np.asarray(timestamp, np.int64)
+            wm_prev = self.watermark
+            if n:
+                self.max_ts_seen = max(self.max_ts_seen, int(ts.max()))
+            if watermark is not None:
+                wm_new = max(wm_prev, int(watermark))
+            elif n:
+                wm_new = max(wm_prev, int(ts.max()) - cfg.allowed_lateness_ms)
+            else:
+                wm_new = wm_prev
+            accepted = (ts >= wm_prev) if n else np.zeros(0, bool)
 
-        ev = self._pad_events(user_id, session_id, ts, code, ip, n)
-        closed, grams, reach, counters = self._device_tick(ev, wm_prev,
-                                                           wm_new)
-        if len(closed["length"]):
-            seg = self.store.append_sessions(SessionSequences(
-                **{k: closed[k] for k in CLOSED_FIELDS}))
-            self._segment_ids.append(seg.seg_id)
-        self.ngram_totals += grams.astype(np.int64)
-        if self.n_stages:
-            self.reach_totals += reach.astype(np.int64)
-        self.watermark = wm_new
-        self.closed_total += counters["closed_sessions"]
-        self.late_dropped += counters["late_dropped"]
-        self.shuffle_dropped += counters["shuffle_dropped"]
-        self.ring_dropped_events += counters["ring_dropped_events"]
-        self.ring_dropped_sessions += counters["ring_dropped_sessions"]
-        self.truncated |= bool(counters["truncated"])
-        return TickResult(
-            watermark=wm_new, accepted=accepted,
-            closed_sessions=counters["closed_sessions"],
-            open_sessions=counters["open_sessions"],
-            late_dropped=counters["late_dropped"],
-            shuffle_dropped=counters["shuffle_dropped"],
-            ring_dropped_events=counters["ring_dropped_events"],
-            ring_dropped_sessions=counters["ring_dropped_sessions"],
-            truncated=bool(counters["truncated"]))
+            with span("streampipe.put"):
+                ev = self._pad_events(user_id, session_id, ts, code, ip, n)
+                with enable_x64():
+                    ev = {k: jnp.asarray(v) for k, v in ev.items()}
+                    wm = (jnp.asarray(wm_prev, jnp.int64),
+                          jnp.asarray(wm_new, jnp.int64))
+            closed, grams, reach, counters = self._device_tick(ev, *wm)
+            if len(closed["length"]):
+                seg = self.store.append_sessions(SessionSequences(
+                    **{k: closed[k] for k in CLOSED_FIELDS}))
+                self._segment_ids.append(seg.seg_id)
+            with span("streampipe.fold"):
+                self.ngram_totals += grams.astype(np.int64)
+                if self.n_stages:
+                    self.reach_totals += reach.astype(np.int64)
+                self.watermark = wm_new
+                self.closed_total += counters["closed_sessions"]
+                self.late_dropped += counters["late_dropped"]
+                self.shuffle_dropped += counters["shuffle_dropped"]
+                self.ring_dropped_events += counters["ring_dropped_events"]
+                self.ring_dropped_sessions += counters["ring_dropped_sessions"]
+                self.truncated |= bool(counters["truncated"])
+            return TickResult(
+                watermark=wm_new, accepted=accepted,
+                closed_sessions=counters["closed_sessions"],
+                open_sessions=counters["open_sessions"],
+                late_dropped=counters["late_dropped"],
+                shuffle_dropped=counters["shuffle_dropped"],
+                ring_dropped_events=counters["ring_dropped_events"],
+                ring_dropped_sessions=counters["ring_dropped_sessions"],
+                truncated=bool(counters["truncated"]))
 
     def flush(self) -> TickResult:
         """Advance the watermark past every possible event: all open
@@ -459,6 +478,20 @@ class _StreamBase:
         z64 = np.zeros(0, np.int64)
         return self.tick(z64, z64, z64, np.zeros(0, np.int32),
                          watermark=WATERMARK_MAX)
+
+    def _pull(self, cb, n_closed, grams, reach, counters):
+        """The tick's outputs to the host: each shard's closed rows
+        ``[:n_closed[shard]]``, the rollup deltas and the counters."""
+        with span("streampipe.wait"):
+            nc = np.asarray(n_closed)
+        pulled = jax.tree.leaves((cb, grams, reach, counters))
+        with span("streampipe.pull", bytes=sum(x.nbytes for x in pulled),
+                  sessions=int(nc.sum())):
+            closed = {k: np.concatenate([np.asarray(v)[sh, : int(nc[sh])]
+                                         for sh in range(len(nc))])
+                      for k, v in cb.items()}
+            counters = {k: int(np.asarray(v)) for k, v in counters.items()}
+            return closed, np.asarray(grams), np.asarray(reach), counters
 
     def _pad_events(self, user_id, session_id, ts, code, ip, n):
         cap = self.cfg.tick_capacity
@@ -520,18 +553,11 @@ class SingleHostStream(_StreamBase):
         return {k: np.asarray(v) for k, v in self._ring.items()}
 
     def _device_tick(self, ev, wm_prev, wm_new):
-        with enable_x64():
-            ring, cb, n_closed, grams, reach, counters = self._tick_jit(
-                self._ring,
-                {k: jnp.asarray(v) for k, v in ev.items()},
-                jnp.asarray(wm_prev, jnp.int64),
-                jnp.asarray(wm_new, jnp.int64),
-                jnp.asarray(self._table))
-        self._ring = ring
-        nc = int(n_closed)
-        closed = {k: np.asarray(v)[:nc] for k, v in cb.items()}
-        counters = {k: int(np.asarray(v)) for k, v in counters.items()}
-        return closed, np.asarray(grams), np.asarray(reach), counters
+        with enable_x64(), span("streampipe.dispatch",
+                                traces=self.trace_counts["tick"]):
+            self._ring, *out = self._tick_jit(self._ring, ev, wm_prev,
+                                              wm_new, self._stage_tab)
+        return self._pull(*out)
 
 
 class StreamPipeline(_StreamBase):
@@ -564,23 +590,13 @@ class StreamPipeline(_StreamBase):
                 for k, v in _init_ring_np(cfg).items()}
 
     def _device_tick(self, ev, wm_prev, wm_new):
-        with enable_x64():
-            with use_mesh(self.mesh):
-                ring, cb, n_closed, grams, reach, counters = self._tick_jit(
-                    self._ring,
-                    jnp.asarray(ev["user_id"]), jnp.asarray(ev["session_id"]),
-                    jnp.asarray(ev["timestamp"]), jnp.asarray(ev["code"]),
-                    jnp.asarray(ev["ip"]), jnp.asarray(ev["valid"]),
-                    jnp.asarray(wm_prev, jnp.int64),
-                    jnp.asarray(wm_new, jnp.int64),
-                    jnp.asarray(self._table))
-        self._ring = ring
-        nc = np.asarray(n_closed)
-        closed = {k: np.concatenate([np.asarray(v)[sh, : int(nc[sh])]
-                                     for sh in range(self.n_shards)])
-                  for k, v in cb.items()}
-        counters = {k: int(np.asarray(v)) for k, v in counters.items()}
-        return closed, np.asarray(grams), np.asarray(reach), counters
+        with enable_x64(), use_mesh(self.mesh), span(
+                "streampipe.dispatch", traces=self.trace_counts["tick"]):
+            self._ring, *out = self._tick_jit(
+                self._ring, ev["user_id"], ev["session_id"], ev["timestamp"],
+                ev["code"], ev["ip"], ev["valid"], wm_prev, wm_new,
+                self._stage_tab)
+        return self._pull(*out)
 
 
 def single_host_stream(cfg: StreamConfig, stages=None,

@@ -67,7 +67,8 @@ def bucket_by_destination(cols, dest: jax.Array, n_dest: int, capacity: int):
     needs the sort permutation to route results back.
     """
     n = dest.shape[0]
-    order = jnp.argsort(dest, stable=True)
+    with jax.named_scope("sort"):
+        order = jnp.argsort(dest, stable=True)
     d_sorted = dest[order]
     idx = jnp.arange(n, dtype=jnp.int32)
     start = jax.ops.segment_min(idx, d_sorted, num_segments=n_dest)
@@ -83,6 +84,7 @@ def bucket_by_destination(cols, dest: jax.Array, n_dest: int, capacity: int):
     return out, order, d_sorted, pos, dropped
 
 
+@jax.named_scope("repartition")
 def keyed_all_to_all(cols, dest: jax.Array, axis: str, n_shards: int,
                      capacity: int):
     """Keyed repartition over mesh axis ``axis`` (call inside shard_map).
