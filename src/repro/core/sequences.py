@@ -76,7 +76,7 @@ class SessionSequences:
         return np.arange(self.max_len)[None, :] < self.stored_length()[:, None]
 
     def session_symbols(self, i: int) -> np.ndarray:
-        return self.symbols[i, : int(self.stored_length()[i])]
+        return self.symbols[i, : min(int(self.length[i]), self.max_len)]
 
     def session_string(self, i: int) -> str:
         """One session in the paper's representation: a valid unicode string,

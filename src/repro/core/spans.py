@@ -8,8 +8,11 @@ in-memory buffer, which ``recent()`` reads without a profiler.
 
 Spans sit at batch boundaries only (a tick, a day, a store append), never
 per session or per event: one costs a few microseconds. Counts are given
-when the span opens. A span never synchronizes with the device: the
-``*.wait`` spans wrap reads that block anyway.
+when the span opens, or, where the block itself learns them, set in the
+dict the span yields (``with span("store.encode_payloads") as counts:
+... counts["bytes"] = n``) and recorded when it closes. A span never
+synchronizes with the device: the ``*.wait`` spans wrap reads that block
+anyway.
 """
 from __future__ import annotations
 
@@ -39,14 +42,19 @@ _open = threading.local()
 
 @contextlib.contextmanager
 def span(name: str, **counts: int):
-    """Time the block as ``name``; ``counts`` label it in both sinks."""
+    """Time the block as ``name``; ``counts``, and those the block adds
+    to the yielded dict, label it in both sinks."""
     stack = _open.__dict__.setdefault("stack", [])
     parent = stack[-1] if stack else None
     stack.append(name)
+    opened = dict(counts)
     start = time.perf_counter_ns()
     try:
-        with TraceAnnotation(name, **counts):
-            yield
+        with TraceAnnotation(name, **opened) as annotation:
+            yield counts
+            late = {k: v for k, v in counts.items() if opened.get(k) != v}
+            if late:
+                annotation.set_metadata(**late)
     finally:
         end = time.perf_counter_ns()
         stack.pop()

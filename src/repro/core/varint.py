@@ -114,13 +114,61 @@ def encode_session(symbols: np.ndarray) -> bytes:
     return "".join(chr(int(c)) for c in cps).encode("utf-8")
 
 
+# UTF-8 lead-byte marks by encoded width (index 0 unused)
+_UTF8_LEAD = np.array([0, 0x00, 0xC0, 0xE0, 0xF0], np.int64)
+_MAX_CODEPOINT = 0x10FFFF
+
+
+def encode_sessions(seqs: SessionSequences
+                    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Every session's UTF-8 string in one array pass over the grid.
+
+    Returns ``(codes, payload, payload_len)``: the stored codes in
+    row-major order, the sessions' UTF-8 bytes back to back (uint8), and
+    the bytes of each session. Row ``j``'s slice of ``payload`` equals
+    ``encode_session`` of its stored symbols, byte for byte; a code whose
+    code point ``chr`` would refuse (``PAD_CODE`` inside a stored length)
+    raises ``ValueError`` here too.
+    """
+    stored = seqs.stored_length().astype(np.int64)
+    row_end = np.cumsum(stored)
+    # flat index of each stored symbol: its row's offset in the grid, minus
+    # the symbols stored before the row, plus its rank among all of them
+    skip = np.arange(len(stored)) * seqs.max_len - (row_end - stored)
+    flat = np.arange(int(stored.sum())) + np.repeat(skip, stored)
+    codes = np.asarray(seqs.symbols).reshape(-1)[flat]
+    cps = code_to_codepoint(codes.astype(np.int64))
+    bad = (cps < 0) | (cps > _MAX_CODEPOINT)
+    if bad.any():
+        k = int(np.argmax(bad))
+        row = int(np.searchsorted(row_end, k, side="right"))
+        raise ValueError(f"session {row}: code {int(codes[k])} maps to code "
+                         f"point {int(cps[k])}, not in range(0x110000)")
+    width = utf8_length(cps)
+    ends = np.cumsum(width)
+    start = ends - width
+    payload = np.empty(int(width.sum()), np.uint8)
+    tail = 6 * (width - 1)          # bits below the lead byte
+    payload[start] = (_UTF8_LEAD[width] | (cps >> tail)).astype(np.uint8)
+    for k in range(1, int(width.max(initial=1))):
+        m = width > k
+        payload[start[m] + k] = (0x80 | ((cps[m] >> (tail[m] - 6 * k)) & 0x3F)
+                                 ).astype(np.uint8)
+    payload_len = np.diff(np.concatenate([[0], ends])[row_end],
+                          prepend=np.int64(0))
+    return codes, payload, payload_len
+
+
 def decode_session(data: bytes) -> np.ndarray:
     cps = np.array([ord(ch) for ch in data.decode("utf-8")], np.int64)
     return codepoint_to_code(cps).astype(np.int32)
 
 
 def encode_store(seqs: SessionSequences) -> list[bytes]:
-    return [encode_session(seqs.session_symbols(i)) for i in range(len(seqs))]
+    """Each session's UTF-8 bytes, split from ``encode_sessions``."""
+    _, payload, payload_len = encode_sessions(seqs)
+    ends = np.cumsum(payload_len)
+    return [payload[a:b].tobytes() for a, b in zip(ends - payload_len, ends)]
 
 
 def raw_log_size_bytes(num_events: int, mean_name_len: float,

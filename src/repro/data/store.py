@@ -12,7 +12,8 @@ module is that store as an append-only collection of immutable columnar
   (``core.dictionary`` frequency order) as unsigned varints.
 * **Session segments** — the materialized relation of §4.2. Each session's
   symbol sequence is stored as the paper's UTF-8 string (small code point =
-  frequent event, ``core.varint.encode_session``); the metadata columns
+  frequent event), every session of a segment encoded in one array pass
+  (``core.varint.encode_sessions``); the metadata columns
   (user, session, ip, start, duration, length) ride along varint-coded.
 * **Per-segment metadata** — row/event counts, ``[min_ts, max_ts]`` (for
   session segments a conservative bound covering every event in every
@@ -179,25 +180,21 @@ def encode_session_segment(seg_id: int, seqs: SessionSequences, *,
                            user_shards: int = 64) -> Segment:
     """Materialized sessions -> an immutable segment (row order preserved).
 
-    Payloads are the paper's UTF-8 session strings; ``max_ts`` is the
+    Payloads are the paper's UTF-8 session strings, encoded in one array
+    pass (``core.varint.encode_sessions``); ``max_ts`` is the
     conservative bound ``max(start_ts + (duration_s + 1) * 1000)`` — it
     covers every event of every session (duration is floor-seconds), so
     time pruning can never drop a matching segment.
     """
-    payloads = _session_payloads(seqs)
-    blob, col_bytes = _session_blob(seqs, payloads)
-    return _session_segment(seg_id, seqs, blob, col_bytes, user_shards)
+    codes, payload, payload_len = varint.encode_sessions(seqs)
+    blob, col_bytes = _session_blob(seqs, payload, payload_len)
+    return _session_segment(seg_id, seqs, codes, blob, col_bytes,
+                            user_shards)
 
 
-def _session_payloads(seqs: SessionSequences) -> list[bytes]:
-    return [varint.encode_session(seqs.session_symbols(j))
-            for j in range(len(seqs))]
-
-
-def _session_blob(seqs: SessionSequences, payloads: list[bytes]
-                  ) -> tuple[bytes, dict[str, int]]:
+def _session_blob(seqs: SessionSequences, payload: np.ndarray,
+                  payload_len: np.ndarray) -> tuple[bytes, dict[str, int]]:
     """The varint metadata columns, then the payloads, as one blob."""
-    payload_len = np.array([len(p) for p in payloads], np.int64)
     blocks = dict(
         start_ts=varint.encode_ivarint(
             np.diff(np.asarray(seqs.start_ts, np.int64),
@@ -209,27 +206,28 @@ def _session_blob(seqs: SessionSequences, payloads: list[bytes]
         length=varint.encode_uvarint(seqs.length),
         payload_len=varint.encode_uvarint(payload_len),
     )
-    blob = b"".join(blocks[k] for k in SESSION_COLS) + b"".join(payloads)
+    blob = b"".join([*(blocks[k] for k in SESSION_COLS), payload])
     col_bytes = {k: len(v) for k, v in blocks.items()}
-    col_bytes["payload"] = int(payload_len.sum())
+    col_bytes["payload"] = len(payload)
     return blob, col_bytes
 
 
-def _session_segment(seg_id: int, seqs: SessionSequences, blob: bytes,
-                     col_bytes: dict[str, int], user_shards: int) -> Segment:
+def _session_segment(seg_id: int, seqs: SessionSequences, codes: np.ndarray,
+                     blob: bytes, col_bytes: dict[str, int],
+                     user_shards: int) -> Segment:
     """The segment around an encoded blob, with the metadata ``scan``
-    prunes on."""
+    prunes on; ``codes`` are the stored symbols ``encode_sessions``
+    returned."""
     n = len(seqs)
     start = np.asarray(seqs.start_ts, np.int64)
     hi = start + (np.asarray(seqs.duration_s, np.int64) + 1) * 1000
-    mask = seqs.mask()
     return Segment(
         seg_id=seg_id, kind="sessions", n=n,
         n_events=int(np.asarray(seqs.length, np.int64).sum()),
         min_ts=int(start.min()) if n else 0,
         max_ts=int(hi.max()) if n else 0,
         user_mask=user_shard_mask(seqs.user_id, user_shards),
-        code_counts=_code_counts(np.asarray(seqs.symbols)[mask]),
+        code_counts=_code_counts(codes),
         col_bytes=col_bytes, blob=blob)
 
 
@@ -405,12 +403,13 @@ class Store:
         events = int(np.asarray(seqs.length, np.int64).sum())
         with span("store.append_sessions", sessions=len(seqs),
                   events=events):
-            with span("store.encode_payloads"):
-                payloads = _session_payloads(seqs)
+            with span("store.encode_payloads") as counts:
+                codes, payload, payload_len = varint.encode_sessions(seqs)
+                counts["bytes"] = len(payload)
             with span("store.encode_columns"):
-                blob, col_bytes = _session_blob(seqs, payloads)
+                blob, col_bytes = _session_blob(seqs, payload, payload_len)
             with span("store.index"):
-                seg = _session_segment(self._take_id(), seqs, blob,
+                seg = _session_segment(self._take_id(), seqs, codes, blob,
                                        col_bytes, self.cfg.user_shards)
                 self.segments.append(seg)
                 self.events_appended += seg.n_events

@@ -58,6 +58,18 @@ def test_variable_length_coding_property():
     assert len(small) < len(large)
 
 
+def test_session_symbols_clamp_each_row_to_the_grid():
+    seqs = _seqs([[1, 2, 3], [4], [0x80, 0x800, 0x10000]])
+    seqs.length[:] = [3, 9, 1]     # row 1 truncated: longer than the grid
+    assert [seqs.session_symbols(i).tolist() for i in range(3)] == \
+        [[1, 2, 3], [4, PAD_CODE, PAD_CODE], [0x80]]
+    seqs.symbols[1, 1:] = [5, 6]
+    assert seqs.as_unicode_strings() == ["\x01\x02\x03", "\x04\x05\x06",
+                                         "\x80"]
+    assert varint.encode_store(seqs) == [
+        varint.encode_session(seqs.session_symbols(i)) for i in range(3)]
+
+
 def test_encoded_size_accounts_masks():
     seqs = _seqs([[0, 1, 2], [5]])
     assert varint.encoded_size_bytes(seqs) == 4  # 4 symbols x 1 byte

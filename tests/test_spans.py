@@ -114,6 +114,8 @@ def test_tick_spans_nest_in_order_with_the_programs_counts(kind):
     stored = sp.store.segments[-1]
     assert _one(recs, "store.append_sessions").counts == dict(
         sessions=res.closed_sessions, events=stored.n_events)
+    assert _one(recs, "store.encode_payloads").counts == dict(
+        bytes=stored.col_bytes["payload"])
 
 
 def test_flush_tick_is_marked_and_a_later_tick_reports_its_traces():
@@ -158,6 +160,8 @@ def test_day_spans_nest_in_order_with_the_programs_counts():
     assert gather.counts == dict(sessions=res.num_sessions())
     assert _one(recs, "store.append_sessions").counts == dict(
         sessions=len(seqs), events=seg.n_events)
+    assert _one(recs, "store.encode_payloads").counts == dict(
+        bytes=seg.col_bytes["payload"])
 
 
 def _replay(sp, ticks):
@@ -264,3 +268,19 @@ def test_a_span_closes_and_records_when_its_block_raises():
     assert recs["test.inner"].parent == "test.outer"
     assert recs["test.inner"].counts == dict(rows=3)
     assert recs["test.after"].parent is None
+
+
+def test_counts_set_inside_the_block_are_recorded(tmp_path):
+    from jax.profiler import ProfileData
+    t0 = time.perf_counter_ns()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("test.late", rows=2) as counts:
+            counts["bytes"] = 9
+    finally:
+        jax.profiler.stop_trace()
+    assert _one(_since(t0), "test.late").counts == dict(rows=2, bytes=9)
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    traced = [dict(e.stats) for p in ProfileData.from_file(path).planes
+              for ln in p.lines for e in ln.events if e.name == "test.late"]
+    assert traced == [dict(rows=2, bytes=9)]

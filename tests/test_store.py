@@ -9,9 +9,11 @@ from hypothesis import given, settings
 import hypothesis.strategies as st
 
 from repro.core import sessionize, varint, SessionSequences
+from repro.core.sessionize import PAD_CODE
 from repro.data.distpipe import single_host_pipeline
 from repro.data.store import (Store, StoreConfig, concat_sequences,
-                              decode_event_segment, decode_session_segment,
+                              SESSION_COLS, decode_event_segment,
+                              decode_session_segment,
                               encode_event_segment, encode_session_segment,
                               scan_matches_sessions, user_shard_mask,
                               _take_rows)
@@ -138,6 +140,133 @@ def test_session_segment_round_trip(n, seed):
     wide = decode_session_segment(seg, min_width=512)
     assert wide.symbols.shape[1] == 512
     assert session_multiset(wide) == session_multiset(seqs)
+
+
+# largest code whose code point chr accepts (codes skip the surrogates)
+MAX_CODE = 0x10FFFF - 0x800
+
+
+def _grid(rows, lengths=None, width=None):
+    """SessionSequences over explicit rows; ``lengths`` may exceed the
+    grid's ``width`` (a truncated session keeps its true length)."""
+    lengths = [len(r) for r in rows] if lengths is None else lengths
+    width = max([len(r) for r in rows], default=0) if width is None \
+        else width
+    s = len(rows)
+    symbols = np.full((s, width), PAD_CODE, np.int32)
+    for j, r in enumerate(rows):
+        symbols[j, : min(len(r), width)] = r[:width]
+    rng = np.random.default_rng(s)
+    return SessionSequences(
+        symbols=symbols, length=np.asarray(lengths, np.int32),
+        user_id=rng.integers(-(1 << 40), 1 << 40, s).astype(np.int64),
+        session_id=rng.integers(0, 1 << 20, s).astype(np.int64),
+        ip=rng.integers(0, 1 << 32, s).astype(np.int64),
+        start_ts=np.sort(rng.integers(0, 1 << 41, s)).astype(np.int64),
+        duration_s=rng.integers(0, 7200, s).astype(np.int32))
+
+
+def _rows(seed, lo, hi, n=40, min_len=1, max_len=12):
+    rng = np.random.default_rng(seed)
+    return [list(rng.integers(lo, hi, rng.integers(min_len, max_len + 1)))
+            for _ in range(n)]
+
+
+# each case: a width-bounded grid, built from (rows, lengths, width)
+SEGMENT_CASES = {
+    "one_byte": lambda: (_rows(1, 0, 0x80), None, None),
+    "two_byte": lambda: (_rows(2, 0x80, 0x800), None, None),
+    "three_byte": lambda: (_rows(3, 0x800, 0x10000 - 0x800), None, None),
+    "four_byte": lambda: (_rows(4, 0x10000 - 0x800, MAX_CODE + 1), None,
+                          None),
+    "mixed_widths": lambda: (_rows(5, 0, MAX_CODE + 1), None, None),
+    "surrogate_shift": lambda: ([[0xD800 - 1, 0xD800], [0xD800],
+                                 [0xD800 - 1], [0, MAX_CODE]], None, None),
+    "clamped_to_max_len": lambda: (_rows(6, 0, 0x900, min_len=8,
+                                         max_len=30), [45] * 40, 8),
+    "zero_length": lambda: ([[], [7, 300], [], [0x20000], []], None, None),
+    "zero_sessions": lambda: ([], None, 4),
+    "one_session": lambda: ([[3, 0x7FF, 0xFFFF - 0x800, 0x10000]], None,
+                            None),
+}
+
+
+def _reference_segment(seqs):
+    """The session blob built session by session from
+    ``varint.encode_session``, and its column sizes."""
+    stored = seqs.stored_length()
+    payloads = [varint.encode_session(seqs.symbols[j, : stored[j]])
+                for j in range(len(seqs))]
+    blocks = [
+        varint.encode_ivarint(np.diff(seqs.start_ts, prepend=np.int64(0))),
+        varint.encode_ivarint(seqs.user_id),
+        varint.encode_ivarint(seqs.session_id),
+        varint.encode_ivarint(seqs.ip),
+        varint.encode_uvarint(seqs.duration_s),
+        varint.encode_uvarint(seqs.length),
+        varint.encode_uvarint([len(p) for p in payloads])]
+    col_bytes = dict(zip(SESSION_COLS, map(len, blocks)),
+                     payload=sum(map(len, payloads)))
+    return b"".join(blocks + payloads), col_bytes, payloads
+
+
+@pytest.mark.parametrize("case", sorted(SEGMENT_CASES))
+def test_session_blob_is_byte_identical_to_per_session_encoding(case):
+    seqs = _grid(*SEGMENT_CASES[case]())
+    blob, col_bytes, payloads = _reference_segment(seqs)
+    seg = encode_session_segment(3, seqs)
+    assert seg.blob == blob
+    assert seg.col_bytes == col_bytes
+    appended = Store().append_sessions(seqs)
+    assert (appended.blob, appended.col_bytes) == (blob, col_bytes)
+    assert varint.encode_store(seqs) == payloads
+    stored = seqs.stored_length()
+    codes = [int(c) for j in range(len(seqs))
+             for c in seqs.symbols[j, : stored[j]]]
+    assert seg.code_counts == appended.code_counts == \
+        {c: codes.count(c) for c in sorted(set(codes))}
+    back = decode_session_segment(seg)
+    assert session_multiset(back) == session_multiset(seqs)
+    assert np.array_equal(back.length, seqs.length)
+
+
+@pytest.mark.parametrize("bad_code", [PAD_CODE, MAX_CODE + 1])
+def test_session_code_outside_unicode_raises_like_chr(bad_code):
+    seqs = _grid([[1, 2, 3], [], [bad_code, 4, 5]])
+    with pytest.raises(ValueError):
+        varint.encode_session(seqs.symbols[2])
+    with pytest.raises(ValueError, match="session 2"):
+        encode_session_segment(0, seqs)
+    store = Store()
+    with pytest.raises(ValueError):
+        store.append_sessions(seqs)
+    assert not store.segments
+    # a symbol past its row's stored length is never encoded
+    seqs.length[2] = 0
+    seqs.symbols[0, 2] = bad_code
+    seqs.length[0] = 2
+    back = decode_session_segment(encode_session_segment(0, seqs))
+    assert back.symbols[0, :2].tolist() == [1, 2]
+    assert back.length.tolist() == [2, 0, 0]
+
+
+@pytest.mark.parametrize("lo,hi", [(0x80, 0x800), (0x800, 0xF800),
+                                   (0xF800, MAX_CODE + 1), (0, MAX_CODE + 1)])
+def test_multibyte_sessions_round_trip_through_segment_and_disk(lo, hi,
+                                                               tmp_path):
+    seqs = _grid(*SEGMENT_CASES["clamped_to_max_len"]())
+    seqs.symbols[seqs.symbols != PAD_CODE] = np.random.default_rng(
+        lo).integers(lo, hi, int((seqs.symbols != PAD_CODE).sum()))
+    seg = encode_session_segment(3, seqs)
+    got = decode_session_segment(seg)
+    assert np.array_equal(got.user_id, seqs.user_id)
+    assert session_multiset(got) == session_multiset(seqs)
+    store = Store()
+    store.append_sessions(seqs)
+    store.save(str(tmp_path / "store"))
+    back = Store.load(str(tmp_path / "store"))
+    assert [g.blob for g in back.segments] == [seg.blob]
+    assert session_multiset(back.sequences()) == session_multiset(seqs)
 
 
 # ---------------------------------------------------------------------------
