@@ -1,5 +1,7 @@
 """The measured window, as the traffic file says: ``closed`` runs full
-steps back to back; ``open`` offers events on a fixed schedule.
+steps back to back and counts what each step returns (the events a log
+step took, the tokens a serving step emitted); ``open`` offers events on
+a fixed schedule.
 
 Open loop: event ``q`` of the window is due at ``q / events_per_s``
 seconds. Each step takes every event due when it starts, up to the
@@ -19,17 +21,17 @@ import numpy as np
 def closed(system, seconds: float, traffic: dict, span) -> dict:
     clock = time.perf_counter
     steps = []
+    done = 0
     t0 = clock()
     while True:
         a = clock()
         with span(system.span):
-            system.step(system.unit)
+            done += system.step(system.unit)
         b = clock()
         steps.append(b - a)
         if b - t0 >= seconds:
             break
-    return dict(loop="closed", events=len(steps) * system.unit,
-                seconds=b - t0, step_s=steps)
+    return dict(loop="closed", events=done, seconds=b - t0, step_s=steps)
 
 
 def open_(system, seconds: float, traffic: dict, span) -> dict:

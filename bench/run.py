@@ -1,31 +1,38 @@
-"""The log tier's benchmark: one cell of BENCHMARK.json, one run.
+"""The benchmark: one cell of BENCHMARK.json, one run.
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
     python3 bench/run.py ... --rehearse    # tiny sizes on the CPU
-    python3 bench/run.py ... --control     # the reference with dedup off
-                                           # in the program's place
+    python3 bench/run.py ... --control     # the system's control in the
+                                           # program's place (must come
+                                           # out not correct)
 
-A run makes one day of client events, or one period of a day, from
-``--seed`` (``loggen.py``),
-builds the cell's system (``systems/<system>.py``, named by its
-configuration) on the cell's chips, warms it up, and then measures for
-``--seconds`` under the cell's traffic (``traffic/<traffic>.json``,
-driven by ``loops.py``). After the window it flushes, reads back what the
-program stored and folded, and compares it with the reference over the
-same events (``check.py``). With ``--trace 0`` the result's metrics are
-the cell's end-to-end metrics; with ``--trace 1`` the window runs under
-the profiler and they are its per-layer metrics, each read by
-``metrics/<name>.py``.
+A run builds the cell's system (``systems/<system>.py``, named by its
+configuration) from ``--seed`` on the cell's chips: the system draws its
+own input (a day of client events, a day's sessions as requests) and
+puts the program on the chips. It warms every shape it will use, then
+measures for ``--seconds`` under the cell's traffic
+(``traffic/<traffic>.json``, driven by ``loops.py``). After the window
+the system finishes what is in flight and compares what the program
+produced with its own plain reference (``System.compared``: each number
+with its limit; ``check.py`` gives the verdict). With ``--trace 0`` the
+result's metrics are the cell's end-to-end metrics; with ``--trace 1``
+the window runs under the profiler and they are its per-layer metrics,
+each read by ``metrics/<name>.py`` from ``ctx``.
+
+A system module holds ``System`` with: ``from_seed(cfg, traffic, seed, mesh)``;
+``span`` and ``sub_spans`` (its host spans); ``unit`` and
+``step(n) -> units done`` (events or tokens); ``warm()``; ``finish()``;
+``compared(window_compiles, control)``; ``tally(window) -> (attempted,
+failed)``; and ``ctx()``, the keys it adds for the metric readers.
 
 The last line of standard output is one JSON object: ``correct``,
-``attempted`` and ``failed`` (events of the window, and those dropped),
-``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and last the
-numbers compared, each with its limit. Standard error ends with the same
-numbers. Without a TPU, or with fewer chips than the cell asks for, the
-run prints no result and exits 2; ``--rehearse`` runs on the CPU, and its
-result names the CPU. JAX keeps compiled programs in
-``$JAX_COMPILATION_CACHE_DIR`` when it is set, else in ``.jax_cache/`` at
-the root of the checkout.
+``attempted`` and ``failed``, ``metrics``, ``device``, with ``--trace 1``
+``breakdown``, and last the numbers compared, each with its limit.
+Standard error ends with the same numbers. Without a TPU, or with fewer
+chips than the cell asks for, the run prints no result and exits 2;
+``--rehearse`` runs on the CPU, and its result names the CPU. JAX keeps
+compiled programs in ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else
+in ``.jax_cache/`` at the root of the checkout.
 """
 from __future__ import annotations
 
@@ -60,9 +67,10 @@ def parse_args(argv=None):
                     help="tiny sizes on the CPU: checks control flow, "
                          "never a chip number")
     ap.add_argument("--control", action="store_true",
-                    help="judge the reference with dedup off in place of "
-                         "what the program stored (must come out not "
-                         "correct)")
+                    help="judge the system's control (the log tier's "
+                         "reference with dedup off, the served model's "
+                         "reference in a lower precision) in place of the "
+                         "program: must come out not correct")
     args = ap.parse_args(argv)
     if args.seed < 0:
         ap.error("--seed is a whole number >= 0")
@@ -132,8 +140,7 @@ def _device_facts(devices) -> dict:
 
 def run(args, t_start: float) -> dict:
     """One run of ``args.workload``; returns the result object."""
-    from bench import check, least_bytes, loggen, loops, manifest, peaks
-    from bench import trace_reduce
+    from bench import check, loops, manifest, peaks, trace_reduce
 
     man = manifest.load(ROOT)
     cell = manifest.cell(man, args.workload)
@@ -157,17 +164,11 @@ def run(args, t_start: float) -> dict:
         _enable_compile_cache()
     counter = CompileCounter()
     try:
-        day = loggen.generate(cfg, args.seed)
-        code_of_name = loggen.assign_codes(day["name_id"],
-                                           len(loggen.name_table()))
-        stages = loggen.stage_codes(cfg["funnel"], code_of_name)
         mesh = jax.sharding.Mesh(devices, ("data",))
         system = _load_module(os.path.join(
-            BENCH, "systems", cfg["system"] + ".py")).System(
-                cfg, day, stages, mesh)
-        for _ in range(cfg["warm_steps"]):
-            system.step(system.unit)
-        dropped_before = system.dropped()
+            BENCH, "systems", cfg["system"] + ".py")).System.from_seed(
+                cfg, traffic, args.seed, mesh)
+        system.warm()
 
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-") \
             if args.trace else None
@@ -204,19 +205,18 @@ def run(args, t_start: float) -> dict:
             planes, spans[0][1:], (system.span,) + system.sub_spans)
         device.update(busy_s=summary["busy_s"],
                       window_s=summary["window_s"])
+        print("device seconds and runs by program:", json.dumps([
+            [k, v, summary["module_n"][k]] for k, v in sorted(
+                summary["module_s"].items(), key=lambda kv: -kv[1])[:12]]),
+            file=sys.stderr)
 
     system.finish()
-    ref = system.reference(dedup=cfg["dedup"])
-    out = system.output()
-    if args.control:
-        out = dict(system.reference(dedup=False), dropped=0, truncated=0)
-    compared = check.compare(out, ref, window_compiles)
+    compared = system.compared(window_compiles, args.control)
 
     ctx = dict(setup_s=setup_s, window=window, span=system.span,
                trace=summary, chips=chips,
                peaks=None if args.rehearse else peaks.peaks(
-                   devices[0].device_kind),
-               bytes_per_event=getattr(least_bytes, cfg["least_bytes"])(cfg))
+                   devices[0].device_kind), **system.ctx())
     wanted = (manifest.per_layer(man, cell["name"]) if args.trace
               else manifest.end_to_end(man, cell["name"]))
     metrics = {}
@@ -225,10 +225,9 @@ def run(args, t_start: float) -> dict:
             BENCH, "metrics", m["name"] + ".py")).read(ctx)
         if value is not None:
             metrics[m["name"]] = dict(value=value, unit=m["unit"])
-    result = dict(correct=check.passed(compared),
-                  attempted=window["events"],
-                  failed=system.dropped() - dropped_before,
-                  metrics=metrics, device=device)
+    attempted, failed = system.tally(window)
+    result = dict(correct=check.passed(compared), attempted=attempted,
+                  failed=failed, metrics=metrics, device=device)
     if summary is not None:
         result["breakdown"] = trace_reduce.breakdown(summary)
     result["compared"] = compared

@@ -7,21 +7,22 @@ from __future__ import annotations
 import numpy as np
 
 from bench.feed import COLUMNS, DayFeed, shift
+from bench.logtier import LogSystem
 from bench.reference import fast
 
 SPAN = "day"
 
 
-class System:
+class System(LogSystem):
     span = SPAN
     sub_spans = ("day.pipeline", "day.store", "day.fold")
 
-    def __init__(self, cfg: dict, day: dict, stages, mesh):
+    def build(self, mesh) -> None:
         from repro.data.distpipe import (DistPipelineConfig,
                                          make_distributed_pipeline)
         from repro.data.store import Store, StoreConfig
-        self.cfg, self.day, self.stages = cfg, day, stages
-        self.feed = DayFeed(day)
+        cfg, stages = self.cfg, self.stages
+        self.feed = DayFeed(self.day)
         self.unit = self.feed.size
         self.dp = make_distributed_pipeline(mesh, DistPipelineConfig(
             alphabet_size=cfg["alphabet_size"],
@@ -40,7 +41,7 @@ class System:
         self.truncated = False
         self.fed = 0
 
-    def step(self, n: int) -> None:
+    def step(self, n: int) -> int:
         if n != self.unit:
             raise ValueError(f"the day job takes whole days of {self.unit} "
                              f"events, not {n}: give it closed-loop traffic")
@@ -58,6 +59,7 @@ class System:
             self.truncated |= res.truncated
         self.days += 1
         self.fed += n
+        return n
 
     def dropped(self) -> int:
         return self.n_dropped

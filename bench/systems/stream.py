@@ -12,19 +12,20 @@ import numpy as np
 
 from bench.feed import COLUMNS, StreamFeed, shift
 from bench.loggen import DAY_MS
+from bench.logtier import LogSystem
 from bench.reference import fast
 
 SPAN = "tick"
 
 
-class System:
+class System(LogSystem):
     span = SPAN
     sub_spans = ()
 
-    def __init__(self, cfg: dict, day: dict, stages, mesh):
+    def build(self, mesh) -> None:
         from repro.data.streampipe import StreamConfig, make_stream_pipeline
-        self.cfg, self.day, self.stages = cfg, day, stages
-        self.feed = StreamFeed(day, cfg["day"]["start_ts_ms"],
+        cfg, stages = self.cfg, self.stages
+        self.feed = StreamFeed(self.day, cfg["day"]["start_ts_ms"],
                                cfg.get("period_ms", DAY_MS))
         self.unit = cfg["tick_capacity"]
         self.sp = make_stream_pipeline(mesh, StreamConfig(
@@ -35,10 +36,11 @@ class System:
             dedup=cfg["dedup"], ngram_n=cfg["ngram_n"]), stages)
         self.fed = 0
 
-    def step(self, n: int) -> None:
+    def step(self, n: int) -> int:
         c = self.feed.take(self.fed, n)
         self.fed += n
         self.sp.tick(*(c[k] for k in COLUMNS))
+        return n
 
     def dropped(self) -> int:
         sp = self.sp

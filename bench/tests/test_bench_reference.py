@@ -1,5 +1,7 @@
 """The whole-array reference equals the copied pure-Python oracle, and the
-feeds keep the properties the reference leans on."""
+feeds keep the properties the reference leans on; the served model's
+requests are each user's day so far, and its plain reference computes
+the program's logits."""
 from __future__ import annotations
 
 import json
@@ -8,12 +10,17 @@ import os
 import numpy as np
 import pytest
 
-from bench import feed, loggen
-from bench.reference import fast, oracle
+from bench import feed, loggen, requests
+from bench.reference import dense_lm, fast, oracle
 
 CFG = json.load(open(os.path.join(os.path.dirname(__file__), "..",
                                   "configs", "client-events-stream.json")))
 COLS = feed.COLUMNS
+# the served model's layers at small widths, in float32
+SMALL = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
+             head_dim=16, d_ff=128, vocab_size=116, tie_embeddings=True,
+             rope_theta=10000.0, norm_eps=1e-5, dtype="float32",
+             param_dtype="float32", max_cache_len=64)
 
 
 def _day(n, seed):
@@ -130,3 +137,78 @@ def test_a_period_thins_the_day_and_keeps_its_sessions():
     assert CFG["period_ms"] == quarter
     got = loggen.generate(dict(CFG, events_per_day=40000), 3)
     assert len(got["user_id"]) == 10000
+
+
+def test_requests_are_each_users_day_so_far_in_order_of_session_end():
+    sess = fast.sessionize(*(_day(6000, 2 ** 31 + 19)[k] for k in COLS))
+    ends = sess["start_ts"] + 1000 * sess["duration_s"]
+    from_ms = int(np.median(ends))
+    got = requests.from_sessions(sess, 40, from_ms)
+    off = sess["offsets"]
+    by_user = np.lexsort((sess["start_ts"], sess["user_id"]))
+    rank = np.empty(len(by_user), np.int64)
+    rank[by_user] = np.arange(len(by_user))
+    want = []
+    for j in range(len(by_user)):
+        mine = [i for i in by_user if sess["user_id"][i] ==
+                sess["user_id"][j] and rank[i] <= rank[j]]
+        toks = [t for i in mine for t in
+                [requests.BOS_ID, *(sess["symbols"][off[i]:off[i + 1]]
+                                    + requests.NUM_SPECIALS),
+                 requests.EOS_ID]]
+        if ends[j] >= from_ms:
+            want.append((ends[j], rank[j], toks[:40], len(mine) > 1))
+    want.sort(key=lambda w: w[:2])
+    assert len(got) == len(want) < len(by_user)
+    for i, (_, _, toks, earlier) in enumerate(want):
+        assert got.prompt(i).tolist() == toks
+        assert got.earlier[i] == earlier
+    assert got.length.max() == 40
+    assert 0 < got.earlier.mean() < 1
+
+
+def test_reference_matches_the_programs_teacher_forced_logits():
+    """At small widths in float32 the reference and the program's own
+    fixed-batch path (``Server.score_batch``: a prefill and then decode
+    through the cache) give the same logits."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs.paper import FULL
+    from repro.models import get_model
+    from repro.serve import ServeConfig, Server
+
+    api = get_model(FULL.with_(**SMALL))
+    params = jax.jit(lambda k: dense_lm.init(k, SMALL))(
+        dense_lm.key_of(2 ** 31 + 3))
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(4, 116, (3, 9)).astype(np.int32)
+    forced = rng.integers(4, 116, (3, 6)).astype(np.int32)
+    got = Server(api, params, ServeConfig(max_new_tokens=6)).score_batch(
+        prompts, forced)
+    want = np.asarray(dense_lm.logits(
+        params, jnp.asarray(np.concatenate([prompts, forced], 1)), SMALL))
+    np.testing.assert_allclose(got, want[:, 8:14], atol=2e-4, rtol=1e-4)
+    # the float8 control computes other logits
+    low = np.asarray(dense_lm.logits(
+        params, jnp.asarray(prompts), SMALL, quantize=True))
+    assert np.abs(low - want[:, :9]).max() > 1e-2
+
+
+def test_shortfall_scores_the_served_tokens():
+    import jax
+    import jax.numpy as jnp
+    params = jax.jit(lambda k: dense_lm.init(k, SMALL))(dense_lm.key_of(7))
+    prompt = np.array([1, 9, 40, 2], np.int32)
+    lg = np.asarray(dense_lm.logits(params, jnp.asarray(prompt[None]),
+                                    SMALL))[0]
+    greedy = [int(np.argmax(lg[-1]))]
+    worst = int(np.argmin(lg[-1]))
+    got = dense_lm.shortfall(params, [(prompt, np.array(greedy)),
+                                      (prompt, np.array([worst]))],
+                             SMALL, length=16, rows=4)
+    assert got[0] == pytest.approx(0.0, abs=1e-6)
+    assert got[1] == pytest.approx(lg[-1].max() - lg[-1].min(), rel=1e-5)
+    with pytest.raises(ValueError, match="does not fit"):
+        dense_lm.shortfall(params, [(np.ones(15, np.int32),
+                                     np.ones(3, np.int32))],
+                           SMALL, length=16, rows=4)

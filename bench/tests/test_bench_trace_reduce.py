@@ -92,6 +92,13 @@ def test_exposed_collective_time():
     assert tr.reduce(_planes(), (50.0, 400.0))["collective_s"] == 0
 
 
+def test_device_time_by_program():
+    s = tr.reduce(_planes(), (50.0, 400.0), ["tick"])
+    # jit_tick(1) runs 90-490 ns, inside the window from 90 to 400
+    assert s["module_s"] == {"jit_tick(1)": pytest.approx(310e-9)}
+    assert s["module_n"] == {"jit_tick(1)": 1}
+
+
 def test_no_device_is_an_error():
     with pytest.raises(RuntimeError):
         tr.reduce(_planes()[1:], (0.0, 1.0))

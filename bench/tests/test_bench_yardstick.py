@@ -1,12 +1,13 @@
-"""The fixed parts of the yardstick: least bytes against hand counts, the
-table of peaks, and the names and units of BENCHMARK.json."""
+"""The fixed parts of the yardstick: least bytes and model operations
+against hand counts, the table of peaks, and the names and units of
+BENCHMARK.json."""
 from __future__ import annotations
 
 import json
 
 import pytest
 
-from bench import least_bytes, manifest, peaks, run
+from bench import least_bytes, manifest, model_flops, peaks, run
 
 
 def test_stream_tick_least_bytes_by_hand():
@@ -66,3 +67,17 @@ def test_manifest_lookups_name_what_is_missing_and_take_any_metric():
     for w in man["workloads"]:
         assert "everywhere" in {m["name"] for m in
                                 manifest.per_layer(man, w["name"])}
+
+
+def test_model_flops_by_hand():
+    m = dict(num_layers=2, d_model=8, head_dim=2, num_heads=4,
+             num_kv_heads=2, d_ff=16, vocab_size=10)
+    # a layer: projections 2*8*2*(2*4 + 2*2) = 384, MLP 6*8*16 = 768;
+    # head 2*8*10 = 160; attention 4*4*2 = 32 per layer and position
+    assert model_flops.per_token(m) == 2 * (384 + 768) + 160
+    assert model_flops.per_context(m) == 2 * 32
+    # positions 3, 4, 5 of one sequence and 0 of another: contexts
+    # 4 + 5 + 6 and 1
+    assert model_flops.positions(m, [3, 0], [6, 1]) == \
+        4 * 2464 + 64 * (4 + 5 + 6 + 1)
+    assert model_flops.positions(m, [5], [5]) == 0

@@ -11,6 +11,9 @@ takes those and a window (the benchmark's own ``window`` host span):
   ``scatter``, ...), summed over the devices;
 * ``op_name_s``: device seconds per op, named by its HLO name and output
   shape (``fusion.636 pred[69632]``), summed over the devices and steps;
+* ``module_s`` and ``module_n``: device seconds and runs per compiled
+  program (the ``XLA Modules`` line, named with its id, as in
+  ``jit_step_fn(3)``), summed over the devices;
 * ``collective_s`` and ``collective_exposed_s``: time of collective ops
   (all-to-all, all-reduce, all-gather, ...), and the part of it in which
   no other op ran on that device, averaged over the devices;
@@ -27,6 +30,7 @@ import re
 
 DEVICE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 NO_SPAN = "none"
 # ops whose events span the ops of their bodies, which the line also holds
 CONTAINERS = ("while", "conditional", "call")
@@ -131,10 +135,18 @@ def reduce(planes: list[dict], window: tuple[float, float],
     busy_ns = coll_ns = exposed_ns = 0.0
     kind_ns: dict[str, float] = {}
     name_ns: dict[str, float] = {}
+    module_ns: dict[str, float] = {}
+    module_n: dict[str, int] = {}
     gaps: list[tuple[str, float]] = []
     for dev in devices:
         clipped, coll, compute = [], [], []
         for ln in dev["lines"]:
+            if ln["name"] == MODULES_LINE:
+                for name, s, d in ln["events"]:
+                    a, b = max(s, lo), min(s + d, hi)
+                    if b > a:
+                        module_ns[name] = module_ns.get(name, 0.0) + (b - a)
+                        module_n[name] = module_n.get(name, 0) + 1
             if ln["name"] != OPS_LINE:
                 continue
             for name, s, d in ln["events"]:
@@ -165,6 +177,8 @@ def reduce(planes: list[dict], window: tuple[float, float],
                 collective_exposed_s=exposed_ns * 1e-9 / len(devices),
                 op_kind_s={k: v * 1e-9 for k, v in kind_ns.items()},
                 op_name_s={k: v * 1e-9 for k, v in name_ns.items()},
+                module_s={k: v * 1e-9 for k, v in module_ns.items()},
+                module_n=module_n,
                 gaps=gaps)
 
 
